@@ -21,11 +21,6 @@ from __future__ import annotations
 
 import jax
 
-# The closed-form model sums per-phase costs that differ by ~9 orders of
-# magnitude; the descent strategies need float64 to keep gradients and
-# regret comparisons meaningful (see .claude/skills/verify/SKILL.md).
-jax.config.update("jax_enable_x64", True)
-
 from repro.core.hadoop.params import CostFactors, HadoopParams, MiB, ProfileStats
 from repro.search import (
     ChunkedEvaluator,
@@ -50,6 +45,15 @@ _SMOKE_REGRET_MAX = 0.05
 
 
 def run(quick: bool = False, smoke: bool = False) -> list[str]:
+    # The closed-form model sums per-phase costs that differ by ~9 orders of
+    # magnitude; the descent strategies need float64 to keep gradients and
+    # regret comparisons meaningful.  Scoped to this run: the process's
+    # precision is left as it was found.
+    with jax.enable_x64(True):
+        return _run(smoke)
+
+
+def _run(smoke: bool) -> list[str]:
     hp = HadoopParams(pNumNodes=16, pNumMappers=128, pUseCombine=True,
                       pSplitSize=256 * MiB)
     st = ProfileStats(sMapSizeSel=1.2, sMapPairsSel=2.0,

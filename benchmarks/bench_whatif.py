@@ -3,10 +3,11 @@
 Three claims, in the order the search subsystem makes them:
 
 1. **Equivalence** — a >= 10^5-config grid evaluated through the chunked,
-   device-sharded path (:class:`repro.search.ChunkedEvaluator`) is
-   bit-for-bit identical to the seed's unchunked single-device
-   ``jit(vmap(model))`` call (padding rows masked out).  Asserted, not
-   eyeballed.
+   device-sharded path (:class:`repro.search.ChunkedEvaluator`) agrees with
+   the seed's unchunked single-device ``jit(vmap(model))`` call (padding
+   rows masked out): equal ``valid`` flags and costs within
+   ``CROSS_PROGRAM_MAX_ULP`` (two executables are not bitwise equal).
+   Asserted, not eyeballed.
 2. **Scale** — the streaming on-device top-k path sweeps a ~10^6-config
    Cartesian space in bounded memory with ONE compile, reporting configs/s.
 3. **Context** — the pure-Python oracle rate, to show why the vectorized
@@ -19,7 +20,15 @@ import numpy as np
 
 from repro.core.hadoop.params import CostFactors, HadoopParams, MiB, ProfileStats
 from repro.core.hadoop.ref import job_model
-from repro.search import ChunkedEvaluator, evaluate_unchunked, search_topk, space_block, space_size
+from repro.search import (
+    CROSS_PROGRAM_MAX_ULP,
+    ChunkedEvaluator,
+    evaluate_unchunked,
+    search_topk,
+    space_block,
+    space_size,
+    ulp_distance,
+)
 from .common import table, timer, write_md
 
 # ~1.2e5 configs: the chunked-vs-unchunked equivalence grid (full mode).
@@ -65,15 +74,18 @@ def run(quick: bool = False) -> list[str]:
     with timer() as t_ch:
         res = ev.evaluate(cols)
 
-    identical = np.array_equal(res.total_cost, ref_cost)
-    assert identical, "chunked/sharded path diverged from unchunked reference"
+    assert np.array_equal(res.outputs["valid"], ref["valid"]), \
+        "chunked/sharded path disagrees with unchunked reference on valid"
+    max_ulp = int(ulp_distance(res.total_cost, ref_cost).max())
+    assert max_ulp <= CROSS_PROGRAM_MAX_ULP, \
+        f"chunked/sharded path {max_ulp} ulp from unchunked reference"
     lines += [
         f"equivalence grid: {n_eq} configs "
         f"({'quick mode, ' if quick else ''}devices={ev.num_devices}, "
         f"chunk={ev.chunk})",
-        f"chunked+sharded == unchunked single-device: "
-        f"**bit-for-bit {identical}** "
-        f"({int(np.isfinite(ref_cost).sum())} valid configs)",
+        f"chunked+sharded vs unchunked single-device: equal valid flags, "
+        f"**max {max_ulp} ulp** (bound {CROSS_PROGRAM_MAX_ULP}; "
+        f"{int(np.isfinite(ref_cost).sum())} valid configs)",
         f"compiles used by the chunked path: {ev.eval_cache_size()}",
         "",
     ]

@@ -21,9 +21,12 @@ import inspect
 import json
 import os
 import time
+from pathlib import Path
 
 from repro.obs import MetricsRegistry
 
+#: root of the checkout (holds the compile cache, see repro.compile_cache)
+ROOT = Path(__file__).resolve().parents[1]
 ART = "artifacts/bench"
 RESULTS_NAME = "BENCH_results.json"
 
@@ -96,6 +99,7 @@ def bench_main(run_fn) -> None:
     """
     import argparse
 
+    from repro.compile_cache import use_compile_cache
     from repro.obs import observe
 
     mod = inspect.getmodule(run_fn)
@@ -115,6 +119,7 @@ def bench_main(run_fn) -> None:
                          "Perfetto-loadable Chrome trace")
     args = ap.parse_args()
     kw = {k: getattr(args, k) for k in ("quick", "smoke") if k in params}
+    use_compile_cache(ROOT)
 
     t0 = time.perf_counter()
     cm = observe(args.trace) if args.trace else contextlib.nullcontext()

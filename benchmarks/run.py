@@ -1,6 +1,6 @@
 """Benchmark driver: ``PYTHONPATH=src python -m benchmarks.run [--quick]``.
 
-One module per paper aspect (DESIGN.md §9 experiment index):
+One module per paper aspect:
 
   E4  bench_sim_vs_analytic  analytic job cost vs task-scheduler simulation
   E5  bench_whatif           what-if engine throughput (vmap vs python)
@@ -47,7 +47,11 @@ def main() -> None:
     ap.add_argument("--only", default="", help="substring filter")
     args = ap.parse_args()
 
-    from .common import RESULTS_NAME, report
+    from repro.compile_cache import use_compile_cache
+
+    from .common import RESULTS_NAME, ROOT, report
+
+    use_compile_cache(ROOT)
 
     failures = 0
     for label, modname in MODULES:

@@ -7,13 +7,13 @@ checkers to turn into findings.  Three mechanics beyond plain interval
 arithmetic:
 
 * **Sub-jaxpr recursion with provenance.**  ``jnp.where`` traces to a
-  ``pjit[name=_where]`` wrapping ``select_n``; every higher-order primitive
-  (``pjit``, ``custom_jvp_call``, ``while``, ``scan``, ``cond``,
+  ``jit[name=_where]`` wrapping ``select_n``; every higher-order primitive
+  (``jit``, ``custom_jvp_call``, ``while``, ``scan``, ``cond``,
   ``remat``/``checkpoint``) is entered with an environment mapping so
   refinement information crosses the call boundary.  Each abstract value
   carries a *provenance token* — the id of the outermost variable it is a
   pass-through of — so a comparison on ``x`` can refine a ``select_n`` case
-  that is ``x`` routed through a pjit boundary.
+  that is ``x`` routed through a jit boundary.
 
 * **Predicate refinement at select_n.**  ``select_n(pred, on_false,
   on_true)`` with ``pred = gt(x, c)`` (or ge/lt/le/isfinite) narrows the
@@ -637,11 +637,11 @@ class _Interp:
     def _sub_jaxpr_vals(self, env, eqn, invars) -> list[AbsValue]:
         return [self.read(env, v) for v in invars]
 
-    def prim_pjit(self, env, eqn):
+    def prim_jit(self, env, eqn):
         closed = eqn.params["jaxpr"]
-        name = eqn.params.get("name", "pjit")
+        name = eqn.params.get("name", "jit")
         ins = self._sub_jaxpr_vals(env, eqn, eqn.invars)
-        self._enter(f"pjit:{name}")
+        self._enter(f"jit:{name}")
         try:
             outs = self.run_closed(closed, ins)
         finally:
@@ -649,7 +649,7 @@ class _Interp:
         return [AbsValue(o.ival, o.origin) for o in outs]
 
     def prim_closed_call(self, env, eqn):
-        return self.prim_pjit(env, eqn)
+        return self.prim_jit(env, eqn)
 
     def prim_core_call(self, env, eqn):
         closed = eqn.params.get("call_jaxpr")

@@ -1,17 +1,13 @@
-"""Version-drift shims for the jax APIs this repo uses.
+"""The jax APIs this repo uses whose spelling has moved between jax releases.
 
-Policy (see README "compat shim policy"): any jax symbol that moved or
-changed signature between the pinned 0.4.x line and current jax is imported
-from HERE, never feature-detected at the call site.  Every module that needs
+Policy (see README "jax compat policy"): any such symbol is imported from
+HERE, never looked up at the call site.  Every module that needs
 ``shard_map`` (the MapReduce pipeline, cross-pod reduction, the EP MoE layout
 and the config-search evaluator) goes through :func:`shard_map` below, so a
-jax upgrade is a one-file change.
+jax upgrade is a one-file change.  Only the installed jax (0.9) is supported.
 
-Currently shimmed:
-
-* ``shard_map`` — ``jax.shard_map`` (>= 0.6, ``check_vma=`` kwarg) vs
-  ``jax.experimental.shard_map.shard_map`` (0.4.x, ``check_rep=`` kwarg).
-  The wrapper normalizes both spellings; callers always pass ``check_vma=``.
+* ``shard_map`` — ``jax.shard_map`` with its ``check_vma=`` kwarg.
+* ``pallas_tpu_compiler_params`` — ``pltpu.CompilerParams``.
 * ``make_mesh`` / ``default_search_devices`` — 1-D mesh construction for the
   sharded config-search evaluator (:mod:`repro.search`).
 """
@@ -32,36 +28,18 @@ __all__ = [
 ]
 
 
-def _resolve_shard_map():
-    """Return (callable, name-of-the-replication-check kwarg)."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None and callable(fn):            # jax >= 0.6
-        return fn, "check_vma"
-    from jax.experimental.shard_map import shard_map as fn_exp  # jax 0.4.x
-
-    return fn_exp, "check_rep"
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Normalized ``shard_map``: works on both old and new jax.
-
-    ``check_vma`` is the new-jax name for the replication check; on 0.4.x it
-    is forwarded as ``check_rep``.  ``None`` keeps the underlying default.
-    """
-    kw = {} if check_vma is None else {_CHECK_KW: check_vma}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map``; ``check_vma=None`` keeps jax's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pallas_tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new jax) vs ``pltpu.TPUCompilerParams``
-    (0.4.x) — same fields, renamed class."""
+    """``pltpu.CompilerParams`` with the given fields."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def default_search_devices() -> list:

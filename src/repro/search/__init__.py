@@ -6,8 +6,9 @@ configurations.  This package is the platform for that at production scale:
 * :mod:`~repro.search.grid`       — streaming Cartesian spaces (no
   materialized 10^6-row products).
 * :mod:`~repro.search.evaluator`  — chunked, padded, device-sharded batched
-  model evaluation (one XLA compile per key-set; bit-for-bit equal to the
-  unchunked path) + the ``valid == 0`` -> exact-simulator escape hatch.
+  model evaluation (one XLA compile per key-set; within
+  ``CROSS_PROGRAM_MAX_ULP`` of the unchunked path) + the ``valid == 0`` ->
+  exact-simulator escape hatch.
 * :mod:`~repro.search.topk`       — streaming on-device top-k merging.
 * :mod:`~repro.search.strategies` — grid / random / coordinate-descent /
   gradient-descent search over any evaluator (gradient descent relaxes the
@@ -19,12 +20,13 @@ configurations.  This package is the platform for that at production scale:
 * :mod:`~repro.search.tpu`        — the TPU step model behind the same
   evaluator interface.
 
-jax version drift (``shard_map`` et al.) is handled by :mod:`repro.compat`.
+jax API spellings (``shard_map`` et al.) come from :mod:`repro.compat`.
 The seed modules ``repro.core.whatif`` and ``repro.core.tuner`` remain as
 thin aliases of this package.
 """
 
 from .evaluator import (
+    CROSS_PROGRAM_MAX_ULP,
     BlockTopK,
     ChunkedEvaluator,
     Evaluator,
@@ -37,6 +39,7 @@ from .evaluator import (
     evaluate_unchunked,
     masked_total,
     sanitize_costs,
+    ulp_distance,
 )
 from .grid import assignment_at, iter_blocks, sample_space, space_block, space_size
 from .service import PhaseQueryResult, QueryResult, QueryStats, WhatIfService
@@ -67,6 +70,8 @@ __all__ = [
     "apply_assignment",
     "sanitize_costs",
     "masked_total",
+    "ulp_distance",
+    "CROSS_PROGRAM_MAX_ULP",
     "space_size",
     "space_block",
     "iter_blocks",

@@ -126,7 +126,7 @@ def test_absint_double_where_guard_suppresses_div0():
     closed = jax.make_jaxpr(f)(jnp.asarray(1.0))
     an = analyze_jaxpr(closed, [Interval(0.0, math.inf, False, True)])
     assert not [e for e in an.events if e.kind == "div0"], (
-        "guard refinement through pjit[_where]/select_n broke")
+        "guard refinement through jit[_where]/select_n broke")
 
 
 def test_absint_ste_interior_exempt_in_grad_mode():

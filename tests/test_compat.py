@@ -1,4 +1,4 @@
-"""Direct coverage of repro.compat — the version-drift shim layer.
+"""Direct coverage of repro.compat — the jax API shim layer.
 
 Every other test exercises compat incidentally (via the evaluator or the
 kernels); these pin the shim's own contract so a jax upgrade that silently
@@ -19,17 +19,6 @@ from repro import compat
 def test_all_exports_exist():
     for name in compat.__all__:
         assert callable(getattr(compat, name)), name
-
-
-def test_resolve_shard_map_kwarg_matches_jax_version():
-    fn, kw = compat._resolve_shard_map()
-    assert callable(fn)
-    assert kw in ("check_vma", "check_rep")
-    # the chosen kwarg must match which API was resolved
-    if getattr(jax, "shard_map", None) is fn:
-        assert kw == "check_vma"
-    else:
-        assert kw == "check_rep"
 
 
 def test_default_search_devices_nonempty():
@@ -70,7 +59,6 @@ def test_shard_map_check_vma_flag_accepted():
     def body(x):
         return x + 1.0
 
-    # both spellings of the replication check must be forwardable
     f = compat.shard_map(
         body, mesh=mesh, in_specs=P("search"), out_specs=P("search"),
         check_vma=False)
@@ -82,12 +70,9 @@ def test_pallas_tpu_compiler_params_fields():
     params = compat.pallas_tpu_compiler_params(
         dimension_semantics=("parallel", "arbitrary"))
     assert tuple(params.dimension_semantics) == ("parallel", "arbitrary")
-    # the resolved class is one of the two known spellings
     from jax.experimental.pallas import tpu as pltpu
 
-    expected = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
-    assert isinstance(params, expected)
+    assert isinstance(params, pltpu.CompilerParams)
 
 
 def test_pallas_tpu_compiler_params_rejects_unknown_field():
