@@ -13,7 +13,7 @@ Two claims, asserted in ``--smoke`` (CI) mode rather than eyeballed:
    allocation happens inside jitted code either way).
 
 The report also shows what a run *records*: the ambient registry
-snapshot (chunks, rows, padding waste, compiles) and the trace event
+snapshot (chunks, rows, padded rows, bytes fetched) and the trace event
 count, as a sanity check that the instrumentation actually fires.
 
 Run:  PYTHONPATH=src python -m benchmarks.bench_obs [--smoke] [--quick]

@@ -48,6 +48,7 @@ import numpy as np
 
 from repro import compat
 from repro.core.hadoop.simulator import SimConfig
+from repro.obs import current as _obs_current
 from repro.search.evaluator import (
     Evaluator,
     ExactCostUnavailable,
@@ -307,7 +308,8 @@ class ClusterEvaluator(Evaluator):
         )
 
     def evaluate(self, overrides: Mapping[str, Any]) -> SearchResult:
-        batched, static, n = split_overrides(self.base_cfg, overrides)
+        with _obs_current().span("evaluator.prepare"):
+            batched, static, n = split_overrides(self.base_cfg, overrides)
         out_blocks: dict[str, list[np.ndarray]] = {}
         for start in range(0, n, self.chunk):
             stop = min(start + self.chunk, n)
@@ -388,6 +390,26 @@ class ClusterEvaluator(Evaluator):
     def _evaluate_rows(self, rows: Mapping[str, np.ndarray],
                        static: Mapping[str, float]) -> dict[str, np.ndarray]:
         """One padded chunk -> per-row metrics (row x trace scenarios)."""
+        with _obs_current().span("cluster.build_scenarios"):
+            scen, ok = self._scenarios(rows, static)
+            n_steps = estimate_steps(scen)
+        out = simulate_batch(scen, n_steps=n_steps, devices=self._devs)
+        shp = (self.chunk, len(self.traces))
+        mean_lat = out["mean_latency"].reshape(shp).mean(axis=1)
+        p95_lat = out["p95_latency"].reshape(shp).mean(axis=1)
+        conv = out["converged"].reshape(shp).min(axis=1)
+        return {
+            "w_meanLat": mean_lat.astype(np.float64),
+            "w_p95Lat": p95_lat.astype(np.float64),
+            "w_makespan": out["makespan"].reshape(shp).mean(axis=1).astype(np.float64),
+            "w_util": out["utilization"].reshape(shp).mean(axis=1).astype(np.float64),
+            "valid": (ok & (conv > 0)).astype(np.float64),
+        }
+
+    def _scenarios(self, rows: Mapping[str, np.ndarray],
+                   static: Mapping[str, float]) -> tuple[dict, np.ndarray]:
+        """One padded chunk's scenario batch (row-major, one scenario per
+        row and trace) and the rows' knob-validity mask."""
         b = self.chunk
         col = lambda k: rows[k] if k in rows else np.full(b, static[k])
         nodes = np.round(col("pNumNodes"))
@@ -462,16 +484,4 @@ class ClusterEvaluator(Evaluator):
             # one-class kernel (no per-class wave state)
             scen["map_slots"] = rep(nodes_s * mpn_s)
             scen["red_slots"] = rep(nodes_s * rpn_s)
-        out = simulate_batch(scen, n_steps=estimate_steps(scen),
-                             devices=self._devs)
-        shp = (b, s)
-        mean_lat = out["mean_latency"].reshape(shp).mean(axis=1)
-        p95_lat = out["p95_latency"].reshape(shp).mean(axis=1)
-        conv = out["converged"].reshape(shp).min(axis=1)
-        return {
-            "w_meanLat": mean_lat.astype(np.float64),
-            "w_p95Lat": p95_lat.astype(np.float64),
-            "w_makespan": out["makespan"].reshape(shp).mean(axis=1).astype(np.float64),
-            "w_util": out["utilization"].reshape(shp).mean(axis=1).astype(np.float64),
-            "valid": (ok & (conv > 0)).astype(np.float64),
-        }
+        return scen, ok

@@ -624,6 +624,7 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
     # unconverged scenarios report inf — the same sentinel `finish` uses.
     lat_safe = jnp.where(jnp.isfinite(latency), latency, 0.0)
     out = dict(
+        steps=st["k"].astype(jnp.int32),    # loop iterations of this lane
         finish=fin,
         map_finish=st["map_fin"],
         latency=latency,
@@ -736,25 +737,16 @@ def _normalize(scen: Mapping[str, np.ndarray]) -> dict[str, np.ndarray]:
     return arrs
 
 
-def simulate_batch(
-    scen: Mapping[str, np.ndarray],
-    *,
-    n_steps: int | None = None,
-    devices=None,
-) -> dict[str, np.ndarray]:
-    """Roll out a batch of scenarios; returns per-scenario metrics plus
-    per-job ``finish`` / ``latency`` arrays.  The batch is padded (edge-
-    replicated) to the device count and sharded over it.  Policy mix and
-    class count are static compile keys: a pure-FIFO homogeneous batch
-    compiles the same lean kernel as before the heterogeneity/preemption
-    extension (callers split rows by policy, as ``bench_cluster`` does)."""
-    devs = tuple(devices) if devices is not None \
-        else tuple(compat.default_search_devices())
+def _prepare(scen: Mapping[str, np.ndarray], n_steps: int | None,
+             n_devs: int) -> tuple[dict, int, int, tuple]:
+    """The normalized batch padded (edge-replicated) to ``n_devs``, its
+    scenario count before padding, and the static compile keys of
+    :func:`_compiled`: the step cap and the kernel flags."""
     if n_steps is None:
         n_steps = estimate_steps(scen)
     arrs = _normalize(scen)
     b = arrs["arrival"].shape[0]
-    pad = (-b) % len(devs)
+    pad = (-b) % n_devs
     if pad:
         arrs = {k: np.concatenate([v, np.repeat(v[-1:], pad, axis=0)])
                 for k, v in arrs.items()}
@@ -771,19 +763,45 @@ def simulate_batch(
         (arrs["topo_racks"] > 1.5)
         & np.isfinite(arrs["topo_cross_bw"]
                       / np.maximum(arrs["topo_oversub"], 1.0))))
+    return arrs, b, n_steps, (with_fair, with_preempt, with_capacity,
+                              with_cloud, with_dag, with_topo)
+
+
+def simulate_batch(
+    scen: Mapping[str, np.ndarray],
+    *,
+    n_steps: int | None = None,
+    devices=None,
+) -> dict[str, np.ndarray]:
+    """Roll out a batch of scenarios; returns per-scenario metrics plus
+    per-job ``finish`` / ``latency`` arrays.  The batch is padded (edge-
+    replicated) to the device count and sharded over it.  Policy mix and
+    class count are static compile keys: a pure-FIFO homogeneous batch
+    compiles the same lean kernel as before the heterogeneity/preemption
+    extension (callers split rows by policy, as ``bench_cluster`` does)."""
+    devs = tuple(devices) if devices is not None \
+        else tuple(compat.default_search_devices())
     ob = _obs_current()
-    with ob.tracer.span("vector_sim.simulate_batch", scenarios=b,
-                        n_steps=n_steps):
-        pre = _compiled.cache_info().misses if ob.enabled else 0
-        out = _compiled(devs, n_steps, with_fair, with_preempt,
-                        with_capacity, with_cloud, with_dag, with_topo)(arrs)
+    with ob.span("vector_sim.simulate_batch"):
+        with ob.span("vector_sim.prepare"):
+            arrs, b, n_steps, flags = _prepare(scen, n_steps, len(devs))
+        with ob.span("vector_sim.dispatch", scenarios=b, n_steps=n_steps):
+            out = _compiled(devs, n_steps, *flags)(arrs)
+        with ob.span("vector_sim.fetch"):
+            steps = out.pop("steps")
+            host = {k: np.asarray(v)[:b] for k, v in out.items()}
+            if ob.enabled:
+                steps = np.asarray(steps)
     if ob.enabled:
         reg = ob.registry
         reg.counter("vector_sim.batches").inc()
         reg.counter("vector_sim.scenarios").inc(b)
-        reg.counter("vector_sim.scenarios_padded").inc(pad)
-        if _compiled.cache_info().misses > pre:
-            reg.counter("vector_sim.compiles").inc()
-            ob.tracer.instant("wave-kernel compile", scope="p",
-                              n_steps=n_steps)
-    return {k: np.asarray(v)[:b] for k, v in out.items()}
+        reg.counter("vector_sim.scenarios_padded").inc(steps.size - b)
+        # every lane of a device's vmapped while_loop runs until that
+        # device's slowest lane is done: lane_steps / loop_steps is the
+        # share of the loop's lane-steps that did work
+        per_dev = steps.reshape(len(devs), -1)
+        reg.counter("vector_sim.lane_steps").inc(int(steps.sum()))
+        reg.counter("vector_sim.loop_steps").inc(
+            per_dev.shape[1] * int(per_dev.max(axis=1).sum()))
+    return host

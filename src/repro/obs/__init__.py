@@ -13,6 +13,11 @@ component via :func:`current`:
     print(ob.registry.snapshot())             # {"service.queries": 42, ...}
     # run.json opens at https://ui.perfetto.dev
 
+Instrumented code times its host work with :meth:`Observability.span`: a
+tracer span (which also lands in any ``jax.profiler`` capture as
+``repro:<name>``, on the device's clock) whose duration is one sample of
+the registry histogram ``<name>_s``.
+
 Off by default: :func:`current` returns null singletons until an
 :func:`observe` context installs live ones, and every instrumented hot path
 guards on ``ob.enabled``, so the disabled cost is one attribute check.
@@ -29,6 +34,7 @@ see the ``observe()`` installed by the driving thread.
 from __future__ import annotations
 
 import contextlib
+import time
 from typing import Iterator
 
 from repro.obs.metrics import (
@@ -39,7 +45,8 @@ from repro.obs.metrics import (
     MetricsRegistry,
     percentile_interp,
 )
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.profile_hooks import install_compile_listener
+from repro.obs.trace import _NULL_SPAN, NULL_TRACER, Tracer
 
 __all__ = [
     "Counter",
@@ -69,6 +76,36 @@ class Observability:
     def enabled(self) -> bool:
         return self.registry.enabled or self.tracer.enabled
 
+    def span(self, name: str, **args):
+        """``with ob.span("evaluator.fetch"): ...`` — a tracer span whose
+        duration is also recorded in the histogram ``<name>_s``.  The
+        shared no-op span while observability is off."""
+        if not self.enabled:
+            return _NULL_SPAN
+        return _TimedSpan(self, name, args)
+
+
+class _TimedSpan:
+    """One :meth:`Observability.span`: the tracer's span around the block,
+    and its ``perf_counter`` duration into the registry on exit."""
+
+    __slots__ = ("_ob", "_name", "_span", "_t0")
+
+    def __init__(self, ob: Observability, name: str, args: dict):
+        self._ob = ob
+        self._name = name
+        self._span = ob.tracer.span(name, **args)
+
+    def __enter__(self) -> "_TimedSpan":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._ob.registry.histogram(self._name + "_s").record(dt)
+
 
 #: the ambient null default — ``current() is NULL_OBS`` means "off".
 NULL_OBS = Observability(NULL_REGISTRY, NULL_TRACER)
@@ -94,9 +131,12 @@ def observe(
     at https://ui.perfetto.dev).  Pass an explicit ``registry``/``tracer``
     to reuse existing instances (e.g. to accumulate across blocks); omitted
     ones are created fresh.  Restores the previous ambient value on exit,
-    so contexts nest.
+    so contexts nest.  Installs the process-wide ``jax.monitoring`` compile
+    listener (:func:`install_compile_listener`), so compiles inside the
+    block are counted as ``jax.backend_compile_duration``.
     """
     global _current
+    install_compile_listener()
     ob = Observability(
         registry if registry is not None else MetricsRegistry(),
         tracer if tracer is not None else Tracer(),
@@ -112,18 +152,10 @@ def observe(
 
 
 def __getattr__(name: str):
-    # Lazy: destrace pulls in repro.cluster (jax), profile_hooks pulls in
-    # jax.profiler — neither belongs in the stdlib-only import path above.
+    # Lazy: destrace pulls in repro.cluster (jax) — not for the stdlib-only
+    # import path above.
     if name == "workload_trace":
         from repro.obs.destrace import workload_trace
 
         return workload_trace
-    if name == "profile_capture":
-        from repro.obs.profile_hooks import profile_capture
-
-        return profile_capture
-    if name == "install_compile_listener":
-        from repro.obs.profile_hooks import install_compile_listener
-
-        return install_compile_listener
     raise AttributeError(f"module 'repro.obs' has no attribute {name!r}")

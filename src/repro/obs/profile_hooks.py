@@ -1,23 +1,18 @@
-"""Bridges into jax's own instrumentation: profiler capture + compile events.
+"""Bridge into jax's own instrumentation: compile events.
 
-Two hooks, both strictly optional and gated on the ambient observability:
+:func:`install_compile_listener` subscribes to ``jax.monitoring``
+backend-compile duration events and forwards them to whatever
+Observability is ambient *at event time*.  jax listeners are global and
+effectively permanent, so exactly one process-wide dispatcher is installed
+(by every :func:`repro.obs.observe`), a no-op while observability is off.
 
-* :func:`profile_capture` — wrap a block in ``jax.profiler`` trace capture
-  (TensorBoard-loadable) *and* an obs span, so device-level profiles line
-  up with the host-side trace.
-* :func:`install_compile_listener` — subscribe to ``jax.monitoring``
-  backend-compile duration events and forward them to whatever
-  Observability is ambient *at event time*.  jax listeners are global and
-  effectively permanent, so we install exactly one process-wide dispatcher
-  that is a no-op while observability is off.
+There is no profiler hook: whoever starts a ``jax.profiler`` capture gets
+the live tracer's spans in it as ``repro:<name>`` annotations.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Iterator
-
-__all__ = ["profile_capture", "install_compile_listener"]
+__all__ = ["install_compile_listener"]
 
 _listener_installed = False
 
@@ -63,23 +58,3 @@ def install_compile_listener() -> bool:
     _listener_installed = True
     return True
 
-
-@contextlib.contextmanager
-def profile_capture(logdir: str) -> Iterator[None]:
-    """Capture a ``jax.profiler`` trace for the block into ``logdir``.
-
-    Pairs the device-level profile with a span on the ambient tracer so the
-    two timelines can be cross-referenced.  Loads in TensorBoard or
-    Perfetto (``logdir/plugins/profile/...``).
-    """
-    import jax
-
-    from repro.obs import current
-
-    ob = current()
-    with ob.tracer.span("jax.profiler.capture", logdir=logdir):
-        jax.profiler.start_trace(logdir)
-        try:
-            yield
-        finally:
-            jax.profiler.stop_trace()

@@ -5,6 +5,10 @@ The observability layer's timeline side.  A :class:`Tracer` collects
 
 * ``span(name)`` — nested wall-clock duration events (``ph: B/E``) on the
   calling thread's track; thread-safe, nesting handled by the viewer.
+  Each span also enters ``jax.profiler.TraceAnnotation("repro:<name>")``,
+  so it lands in any ``jax.profiler`` capture on the device's clock
+  (nearly free while no capture is open).  Every other event kind stays
+  in this tracer's JSON only: virtual-time tracks never reach the profiler.
 * ``complete(...)`` — a single ``ph: X`` event with an explicit start and
   duration, used for *virtual-time* tracks (the cluster DES emits simulated
   seconds as microseconds; see :mod:`repro.obs.destrace`).
@@ -34,10 +38,21 @@ from typing import Iterator
 __all__ = ["Tracer", "NULL_TRACER"]
 
 
-class _Span:
-    """Context manager emitting B on enter / E on exit for one tracer."""
+_TraceAnnotation = None      # jax.profiler.TraceAnnotation, imported on first span
 
-    __slots__ = ("_tracer", "_name", "_args")
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation("repro:" + name)
+
+
+class _Span:
+    """Context manager emitting B on enter / E on exit for one tracer, and
+    the same span as a ``repro:<name>`` annotation of the profiler's trace."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict | None):
         self._tracer = tracer
@@ -46,9 +61,12 @@ class _Span:
 
     def __enter__(self) -> "_Span":
         self._tracer._emit("B", self._name, args=self._args)
+        self._ann = _annotation(self._name)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
         self._tracer._emit("E", self._name)
 
 

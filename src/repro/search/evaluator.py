@@ -33,7 +33,6 @@ for the TPU-side tuner, so every strategy in
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping
 
@@ -479,52 +478,32 @@ class ChunkedEvaluator(Evaluator):
         :data:`CROSS_PROGRAM_MAX_ULP` (padding rows are computed but dropped
         here).
         """
-        batched, static, n = self._split(overrides)
         ob = _obs_current()
-        t0 = time.perf_counter() if ob.enabled else 0.0
+        with ob.span("evaluator.prepare"):
+            batched, static, n = self._split(overrides)
         out_blocks: dict[str, list[np.ndarray]] = {}
-        with ob.tracer.span("evaluator.evaluate", rows=n):
+        with ob.span("evaluator.evaluate", rows=n):
             for start in range(0, n, self.chunk):
                 stop = min(start + self.chunk, n)
                 cols, _ = self._pad(batched, start, stop)
-                pre = self.eval_cache_size() if ob.enabled else 0
-                out = self._eval_fn(cols, static)
+                with ob.span("evaluator.dispatch"):
+                    out = self._eval_fn(cols, static)
+                with ob.span("evaluator.fetch"):
+                    host = {k: np.asarray(v) for k, v in out.items()}
                 if ob.enabled:
-                    self._note_chunk(ob, batched, pre, self.eval_cache_size())
-                for k, v in out.items():
-                    out_blocks.setdefault(k, []).append(
-                        np.asarray(v)[: stop - start])
+                    reg = ob.registry
+                    reg.counter("evaluator.chunks").inc()
+                    reg.counter("evaluator.d2h_bytes").inc(
+                        sum(v.nbytes for v in host.values()))
+                for k, v in host.items():
+                    out_blocks.setdefault(k, []).append(v[: stop - start])
         if ob.enabled:
-            self._note_evaluate(ob, n, time.perf_counter() - t0)
+            padded = -(-n // self.chunk) * self.chunk - n
+            ob.registry.counter("evaluator.rows").inc(n)
+            ob.registry.counter("evaluator.rows_padded").inc(padded)
         outputs = {k: np.concatenate(v) for k, v in out_blocks.items()}
         total = masked_total(outputs, self.cost_key)
         return SearchResult(overrides=batched, outputs=outputs, total_cost=total)
-
-    # ---------------- observability (host-side only; never inside jit) ----
-
-    def _note_chunk(self, ob, batched, pre_compiles: int,
-                    post_compiles: int) -> None:
-        """Per-chunk accounting: the one-compile-per-key-set contract as a
-        runtime-observable metric."""
-        ob.registry.counter("evaluator.chunks").inc()
-        if post_compiles > pre_compiles:
-            key_set = ",".join(sorted(batched))
-            ob.registry.counter("evaluator.compiles").inc()
-            ob.tracer.instant("xla compile", scope="p", key_set=key_set)
-
-    def _note_evaluate(self, ob, n: int, elapsed: float) -> None:
-        n_chunks = -(-n // self.chunk)
-        padded = n_chunks * self.chunk - n
-        reg = ob.registry
-        reg.counter("evaluator.rows").inc(n)
-        reg.counter("evaluator.rows_padded").inc(padded)
-        reg.histogram("evaluator.evaluate_s").record(elapsed)
-        if elapsed > 0:
-            ob.tracer.counter(
-                "evaluator",
-                configs_per_s=n / elapsed,
-                padding_waste=padded / (n + padded) if n + padded else 0.0,
-            )
 
     def report(self, overrides: Mapping[str, Any]) -> CostReport:
         """Typed per-phase report for these rows (the ``repro.api`` path).
@@ -565,29 +544,31 @@ class ChunkedEvaluator(Evaluator):
     def chunk_topk(self, overrides: Mapping[str, np.ndarray], k: int) -> BlockTopK:
         """On-device top-k of one block (k cheapest valid / invalid rows);
         only 2k scalars + indices come back to the host."""
-        batched, static, n = self._split(overrides)
-        if n > self.chunk:
-            raise ValueError(f"block of {n} rows exceeds chunk={self.chunk}")
-        cols, mask = self._pad(batched, 0, n)
-        kk = min(k, self.chunk)
         ob = _obs_current()
-        with ob.tracer.span("evaluator.chunk_topk", rows=n, k=kk):
-            pre = self.topk_cache_size() if ob.enabled else 0
-            costs, idx, inv_c, inv_i, n_valid, reasons = self._topk_fn(
-                cols, static, mask, k=kk)
+        with ob.span("evaluator.chunk_topk"):
+            with ob.span("evaluator.prepare"):
+                batched, static, n = self._split(overrides)
+                if n > self.chunk:
+                    raise ValueError(f"block of {n} rows exceeds chunk={self.chunk}")
+                cols, mask = self._pad(batched, 0, n)
+            kk = min(k, self.chunk)
+            with ob.span("evaluator.dispatch"):
+                costs, idx, inv_c, inv_i, n_valid, reasons = self._topk_fn(
+                    cols, static, mask, k=kk)
+            with ob.span("evaluator.fetch"):
+                host = [np.asarray(a) for a in (costs, idx, inv_c, inv_i, n_valid)]
+                counts = {name: np.asarray(v) for name, v in reasons.items()}
         if ob.enabled:
             reg = ob.registry
             reg.counter("evaluator.topk_blocks").inc()
             reg.counter("evaluator.rows").inc(n)
             reg.counter("evaluator.rows_padded").inc(self.chunk - n)
-            if self.topk_cache_size() > pre:
-                reg.counter("evaluator.compiles").inc()
-                ob.tracer.instant("xla compile", scope="p",
-                                  key_set=",".join(sorted(batched)))
+            reg.counter("evaluator.d2h_bytes").inc(
+                sum(a.nbytes for a in host) + sum(a.nbytes for a in counts.values()))
+        costs, idx, inv_c, inv_i, n_valid = host
         return BlockTopK(
-            np.asarray(costs), np.asarray(idx),
-            np.asarray(inv_c), np.asarray(inv_i), int(n_valid),
-            {name: int(v) for name, v in reasons.items() if int(v)},
+            costs, idx, inv_c, inv_i, int(n_valid),
+            {name: int(v) for name, v in counts.items() if int(v)},
         )
 
     def grad_objective(self):
@@ -612,8 +593,8 @@ class ChunkedEvaluator(Evaluator):
         p2, s2, c2 = apply_assignment(*self._psc, assignment)
         return float(simulate_job(p2, s2, c2, SimConfig()).makespan)
 
-    # compile-cache introspection (used by tests/bench to prove chunking
-    # keeps one compile across grid sizes)
+    # compile-cache introspection (used by tests to prove chunking keeps
+    # one compile across grid sizes)
     def eval_cache_size(self) -> int:
         return self._eval_fn._cache_size()
 

@@ -432,17 +432,26 @@ class WhatIfService:
         coalescing only queries that sweep the head query's key-set (so the
         chunk runs exactly the executable their sequential calls would).
         Returns ``(query, query_row_start, n_rows, chunk_offset)`` segments;
-        a query leaves the queue once all its rows are packed."""
+        a query leaves the queue once all its rows are packed.  A query's
+        wait in the queue ends when its first rows are packed
+        (``service.queue_wait_s``, one sample per query)."""
         segments: list[tuple[_Query, int, int, int]] = []
         offset = 0
         sig = None
-        for q in self._queue.items():       # FIFO snapshot; worker-only pops
+        items = self._queue.items()         # FIFO snapshot; worker-only pops
+        ob = _obs_current()
+        now = time.perf_counter() if ob.enabled else 0.0
+        for q in items:
             if offset >= chunk:
                 break
             if sig is None:
                 sig = q.sig
             elif q.sig != sig:
                 continue                    # different executable: next chunk
+            if q.taken == 0 and ob.enabled:
+                ob.registry.histogram("service.queue_wait_s").record(
+                    now - q.t_submit)
+                ob.tracer.async_instant("query.packed", q.qid)
             take = min(chunk - offset, q.n - q.taken)
             segments.append((q, q.taken, take, offset))
             q.taken += take

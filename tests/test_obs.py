@@ -11,8 +11,10 @@ Three layers of coverage:
   timestamps), and off-by-default means *zero* events recorded;
 * the integrations: DES virtual-time swimlanes (golden: deterministic,
   phase-carved, shuffle_end invariant), the evaluator under
-  ``api.observe`` (same numbers, live counters), the serve-loop's
-  read-only stats view, and calibration's grad-norm series.
+  ``api.observe`` (same numbers, live counters), the spans that split a
+  chunk, a rollout and a query's wait (in ``jax.profiler``'s own trace
+  too) and their counters, the serve-loop's read-only stats view, and
+  calibration's grad-norm series.
 """
 
 import json
@@ -409,6 +411,182 @@ def test_api_observe_evaluator_counters_and_equivalence(tmp_path):
     _assert_valid_chrome_trace(doc["traceEvents"])
     assert any(e["name"] == "evaluator.evaluate"
                for e in doc["traceEvents"])
+
+
+def _small_evaluator(chunk=64):
+    from repro.core.hadoop.params import CostFactors, HadoopParams, MiB, ProfileStats
+    from repro.search import ChunkedEvaluator
+
+    hp = HadoopParams(pNumNodes=4, pNumMappers=32, pNumReducers=8,
+                      pSplitSize=64 * MiB)
+    return ChunkedEvaluator(hp, ProfileStats(), CostFactors(), chunk=chunk)
+
+
+def test_span_helper_times_into_a_histogram_and_is_free_when_off():
+    from repro.obs import _TimedSpan
+    from repro.obs.trace import _NULL_SPAN
+
+    assert NULL_OBS.span("x", a=1) is _NULL_SPAN
+    with observe() as ob:
+        for _ in range(3):
+            with ob.span("outer", n=1):
+                with ob.span("inner"):
+                    pass
+        assert isinstance(ob.span("y"), _TimedSpan)
+    inner = ob.registry.histogram("inner_s").samples()
+    outer = ob.registry.histogram("outer_s").samples()
+    assert len(inner) == len(outer) == 3
+    assert all(0.0 <= i <= o for i, o in zip(inner, outer))
+    _assert_valid_chrome_trace(ob.tracer.events())
+    assert [e["name"] for e in ob.tracer.events() if e["ph"] == "B"] == \
+        ["outer", "inner"] * 3
+
+
+def test_live_spans_land_in_the_profiler_trace_nested(tmp_path):
+    """A live tracer's spans reach jax.profiler's own trace as repro:<name>
+    host events, on the profiler's clock: the fetch lies inside the chunk's
+    span and starts after the dispatch ended."""
+    import jax
+    from jax.profiler import ProfileData
+
+    ev = _small_evaluator()
+    rows = {"pSortMB": np.array([50.0, 100.0, 200.0])}
+    ev.chunk_topk(rows, 2)                        # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with observe():
+            ev.chunk_topk(rows, 2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro:"):
+                    spans[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    assert set(spans) == {"repro:evaluator.chunk_topk", "repro:evaluator.prepare",
+                          "repro:evaluator.dispatch", "repro:evaluator.fetch"}
+    top = spans["repro:evaluator.chunk_topk"]
+    fetch = spans["repro:evaluator.fetch"]
+    assert top[0] <= fetch[0] and fetch[1] <= top[1]
+    assert fetch[0] >= spans["repro:evaluator.dispatch"][1]
+
+
+def test_evaluator_spans_split_each_chunk_and_count_fetched_bytes():
+    ev = _small_evaluator(chunk=64)
+    rows = {"pSortMB": np.linspace(16.0, 400.0, 150)}      # 3 chunks
+    with observe() as ob:
+        res = ev.evaluate(rows)
+        ev.chunk_topk({"pSortMB": rows["pSortMB"][:40]}, 5)
+    reg = ob.registry
+    count = {n: reg.histogram(f"evaluator.{n}_s").count
+             for n in ("prepare", "evaluate", "dispatch", "fetch", "chunk_topk")}
+    # evaluate: one split, 3 chunks; chunk_topk: one of each
+    assert count == {"prepare": 2, "evaluate": 1, "dispatch": 4, "fetch": 4,
+                     "chunk_topk": 1}
+    import jax
+
+    # every output column of each 64-row chunk, and everything the top-k
+    # program returns for its block
+    per_chunk = sum(64 * v.itemsize for v in res.outputs.values())
+    batched, static, n = ev._split({"pSortMB": rows["pSortMB"][:40]})
+    cols, mask = ev._pad(batched, 0, n)
+    topk = sum(np.asarray(a).nbytes for a in jax.tree_util.tree_leaves(
+        ev._topk_fn(cols, static, mask, k=5)))
+    assert reg.counter("evaluator.d2h_bytes").value == 3 * per_chunk + topk
+    assert reg.counter("evaluator.chunks").value == 3
+    assert reg.counter("evaluator.rows").value == 190
+
+
+def _wave_lane(n_maps, arrivals, slots=2.0, cost=10.0):
+    j = len(n_maps)
+    return {"arrival": np.asarray(arrivals, float),
+            "n_maps": np.asarray(n_maps, float), "n_reds": np.zeros(j),
+            "map_cost": np.full(j, cost), "red_work": np.zeros(j),
+            "shuffle": np.zeros(j), "map_slots": np.asarray(slots),
+            "red_slots": np.asarray(1.0), "slowstart": np.asarray(1.0)}
+
+
+def _fifo_event_count(n_maps, arrivals, slots=2, cost=10.0):
+    """Distinct event times of a map-only FIFO lane, by list scheduling:
+    every arrival and every task end is one step of the rollout."""
+    free = [0.0] * slots
+    events = set(arrivals)
+    for n, t in sorted(zip(n_maps, arrivals), key=lambda x: x[1]):
+        for _ in range(n):
+            i = int(np.argmin(free))
+            free[i] = max(free[i], t) + cost
+            events.add(free[i])
+    return len(events)
+
+
+def test_rollout_lane_counters_match_a_numpy_count_of_events():
+    from repro.cluster.vector_sim import simulate_batch
+
+    lanes = [([4, 1], [0.0, 0.0]), ([2, 1], [0.0, 35.0]),
+             ([6, 3], [0.0, 3.0]), ([1, 1], [0.0, 0.0])]
+    scen = {k: np.stack([_wave_lane(*ln)[k] for ln in lanes])
+            for k in _wave_lane(*lanes[0])}
+    steps = [_fifo_event_count(*ln) for ln in lanes]
+    assert steps == [4, 4, 7, 2]
+    with observe() as ob:
+        simulate_batch(scen)
+    reg = ob.registry
+    assert reg.counter("vector_sim.lane_steps").value == sum(steps)
+    assert reg.counter("vector_sim.loop_steps").value == len(lanes) * max(steps)
+    for part in ("simulate_batch", "prepare", "dispatch", "fetch"):
+        assert reg.histogram(f"vector_sim.{part}_s").count == 1
+
+
+def test_tracing_leaves_outputs_bit_for_bit_and_keys_unchanged():
+    from repro.cluster.vector_sim import simulate_batch
+
+    ev = _small_evaluator()
+    rows = {"pSortMB": np.linspace(16.0, 400.0, 50),
+            "pNumReducers": np.arange(50, dtype=float)}
+    scen = {k: np.stack([_wave_lane([3, 2], [0.0, 4.0])[k],
+                         _wave_lane([5, 1], [0.0, 1.0])[k]])
+            for k in _wave_lane([1], [0.0])}
+    blk0, sim0 = ev.chunk_topk(rows, 4), simulate_batch(scen)
+    with observe():
+        blk, sim = ev.chunk_topk(rows, 4), simulate_batch(scen)
+    for f in ("costs", "idx", "inv_costs", "inv_idx"):
+        assert np.array_equal(getattr(blk0, f), getattr(blk, f)), f
+    assert (blk0.n_valid, blk0.reason_counts) == (blk.n_valid, blk.reason_counts)
+    assert sim.keys() == sim0.keys() and "steps" not in sim
+    assert all(np.array_equal(sim0[k], sim[k]) for k in sim)
+
+
+def test_queue_wait_is_one_sample_per_query_within_its_latency():
+    from repro.search import WhatIfService
+
+    ev = _small_evaluator(chunk=32)
+    with observe() as ob:
+        with WhatIfService(ev) as svc:
+            futs = [svc.submit({"pSortMB": np.full(n, 50.0 + n)})
+                    for n in (1, 40, 7, 70, 3)]
+            lat = [f.result().stats.latency_s for f in futs]
+    waits = ob.registry.histogram("service.queue_wait_s").samples()
+    assert len(waits) == len(futs)
+    assert all(w >= 0.0 for w in waits)
+    # per query on its async track: submitted <= packed <= resolved
+    marks = {}
+    for e in ob.tracer.events():
+        if e["ph"] in ("b", "n", "e"):
+            marks.setdefault(e["id"], {})[e["ph"]] = e["ts"]
+    assert len(marks) == len(futs)
+    assert all(m["b"] <= m["n"] <= m["e"] for m in marks.values())
+    # each wait is at most its own latency, so also order statistic-wise
+    assert all(w <= x for w, x in zip(sorted(waits), sorted(lat)))
+
+
+def test_observe_counts_compiles_through_the_jax_listener():
+    import jax
+
+    with observe() as ob:
+        jax.jit(lambda x: x * 3.0 + 1.0)(np.arange(7.0))
+    assert ob.registry.counter("jax.backend_compile_duration").value >= 1
 
 
 # ------------------------------------------------------------------
