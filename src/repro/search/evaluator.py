@@ -293,24 +293,33 @@ def split_overrides(
     its key, so service-normalized rows and direct calls see bit-identical
     inputs.  One implementation shared by every chunked evaluator (Hadoop
     job model here, cluster planner in :mod:`repro.cluster.evaluator`) so
-    the contract cannot drift."""
+    the contract cannot drift.
+
+    Host columns are cast on the host (the same numpy cast ``jnp.asarray``
+    makes before it puts a host input on the device) into fresh arrays
+    that never alias the caller's; a jax Array column is cast where it
+    lives, as ``jnp.asarray`` casts it, then copied to the host.  Scalars
+    become device scalars, one asynchronous put each."""
     static = dict(base_cfg)
     batched: dict[str, np.ndarray] = {}
     n = None
     for k, v in overrides.items():
         if k not in base_cfg:
             raise KeyError(f"unknown config key: {k!r}")
-        arr = jnp.asarray(v, dtype=base_cfg[k].dtype)
+        dtype = base_cfg[k].dtype
+        if np.ndim(v) == 0:
+            static[k] = jnp.asarray(v, dtype=dtype)
+            continue
+        if isinstance(v, jax.Array):
+            v = jnp.asarray(v, dtype=dtype)
+        arr = np.array(v, dtype=dtype, copy=True)
         if arr.ndim > 1:
             raise ValueError(f"override {k!r} must be scalar or 1-D")
-        if arr.ndim == 1:
-            if n is None:
-                n = arr.shape[0]
-            elif arr.shape[0] != n:
-                raise ValueError("all batched overrides must share a length")
-            batched[k] = np.asarray(arr)
-        else:
-            static[k] = arr
+        if n is None:
+            n = arr.shape[0]
+        elif arr.shape[0] != n:
+            raise ValueError("all batched overrides must share a length")
+        batched[k] = arr
     if n is None:
         raise ValueError("at least one override must be batched")
     if n == 0:

@@ -17,6 +17,8 @@ import subprocess
 import sys
 import textwrap
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -35,6 +37,7 @@ from repro.search import (
     space_block,
     space_size,
 )
+from repro.search.evaluator import split_overrides
 
 P = HadoopParams(pNumNodes=8, pNumMappers=64, pNumReducers=16, pSplitSize=128 * MiB)
 S = ProfileStats(sMapSizeSel=0.8, sReduceSizeSel=0.5)
@@ -133,6 +136,165 @@ def test_scalar_overrides_and_errors():
     with pytest.raises(ValueError):
         ev.evaluate({"pSortMB": np.array([1.0, 2.0]),
                      "pSortFactor": np.array([1.0])})
+
+
+# ------------------------------------------------------------------
+# split_overrides: columns cast on the host
+# ------------------------------------------------------------------
+
+
+def _split_via_device(base_cfg, overrides):
+    """The reference split: every override cast by ``jnp.asarray`` on the
+    device, batched columns copied back to the host."""
+    static = dict(base_cfg)
+    batched = {}
+    n = None
+    for k, v in overrides.items():
+        if k not in base_cfg:
+            raise KeyError(f"unknown config key: {k!r}")
+        arr = jnp.asarray(v, dtype=base_cfg[k].dtype)
+        if arr.ndim > 1:
+            raise ValueError(f"override {k!r} must be scalar or 1-D")
+        if arr.ndim == 1:
+            if n is None:
+                n = arr.shape[0]
+            elif arr.shape[0] != n:
+                raise ValueError("all batched overrides must share a length")
+            batched[k] = np.asarray(arr)
+        else:
+            static[k] = arr
+    if n is None:
+        raise ValueError("at least one override must be batched")
+    if n == 0:
+        raise ValueError("batched overrides are empty (0-length grid)")
+    return batched, static, n
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+# float32 rounding edges: ties to even either way, subnormals and the tie
+# below the smallest one, overflow (incl. the tie above float32's max), signed
+# zeros and the non-finite values
+EDGE_F64 = np.array([
+    1 + 2.0**-24, 1 + 3 * 2.0**-24, -(1 + 2.0**-24), 1e-40, 2.0**-149,
+    2.0**-150, 1.5 * 2.0**-150, 1e39, -1e39, 3.4028235677973366e38,
+    0.0, -0.0, np.nan, -np.nan, np.inf, -np.inf, 0.1, 1e20,
+])
+EDGE_I64 = np.array([0, 1, -1, 2**24 + 1, 2**53 + 1, 2**62, -(2**63)] * 2 + [7, 9, 11, 13],
+                    dtype=np.int64)
+assert EDGE_I64.size == EDGE_F64.size
+
+# each builds one column inside the base dtype's x64 setting
+COLUMNS = {
+    "float64": lambda: EDGE_F64.copy(),
+    "float32": lambda: EDGE_F64.astype(np.float32),
+    "int64": lambda: EDGE_I64.copy(),
+    "bool": lambda: np.arange(EDGE_F64.size) % 3 == 0,
+    "float list": lambda: EDGE_F64.tolist(),
+    "int list": lambda: [int(x) for x in EDGE_I64],
+    "jax float64": lambda: jnp.asarray(EDGE_F64),
+    "jax float32": lambda: jnp.asarray(EDGE_F64.astype(np.float32)),
+    "jax int": lambda: jnp.asarray(EDGE_I64 // 4),
+}
+# (base dtype, x64): float32 and bfloat16 as the chip runs them and as the
+# tests' x64 default holds them; float64 only exists under x64
+BASES = [("float32", False), ("float32", True), ("bfloat16", False),
+         ("bfloat16", True), ("float64", True)]
+
+
+@pytest.mark.parametrize("column", list(COLUMNS))
+@pytest.mark.parametrize("base", BASES, ids=lambda b: f"{b[0]}-x64{int(b[1])}")
+def test_split_overrides_matches_device_round_trip(base, column):
+    dt, x64 = base
+    keys = ["col", "col2", "s_float", "s_int", "s_bool", "s_np", "s_jax", "kept"]
+    with jax.enable_x64(x64), np.errstate(over="ignore", invalid="ignore"):
+        base_cfg = {k: jnp.asarray(0.5, dtype=dt) for k in keys}
+        overrides = {
+            "col": COLUMNS[column](), "col2": np.linspace(0.0, 1.0, EDGE_F64.size),
+            "s_float": 0.1, "s_int": 7, "s_bool": True,
+            "s_np": np.float64(2.0**-150), "s_jax": jnp.asarray(1e39),
+        }
+        batched, static, n = split_overrides(base_cfg, overrides)
+        ref_b, ref_s, ref_n = _split_via_device(base_cfg, overrides)
+    assert n == ref_n == EDGE_F64.size
+    assert batched.keys() == ref_b.keys() == {"col", "col2"}
+    for k, v in batched.items():
+        assert type(v) is np.ndarray and v.dtype == ref_b[k].dtype == np.dtype(dt), k
+        assert np.array_equal(_bits(v), _bits(ref_b[k])), k
+    assert static.keys() == ref_s.keys() == set(keys)
+    for k, v in static.items():
+        assert isinstance(v, jax.Array) and v.dtype == ref_s[k].dtype, k
+        assert np.array_equal(_bits(v), _bits(ref_s[k])), k
+    assert static["kept"] is base_cfg["kept"]
+
+
+@pytest.mark.parametrize("overrides, err, match", [
+    ({"nope": np.ones(2)}, KeyError, "unknown config key: 'nope'"),
+    ({"a": np.ones((2, 2))}, ValueError, "override 'a' must be scalar or 1-D"),
+    ({"a": np.ones(2), "b": np.ones(3)}, ValueError, "must share a length"),
+    ({"a": 1.0}, ValueError, "at least one override must be batched"),
+    ({"a": np.ones(0)}, ValueError, r"empty \(0-length grid\)"),
+], ids=["unknown", "2-d", "lengths", "no-batched", "empty"])
+def test_split_overrides_errors_unchanged(overrides, err, match):
+    base_cfg = {"a": jnp.asarray(0.0, jnp.float32), "b": jnp.asarray(0.0, jnp.float32)}
+    for split in (split_overrides, _split_via_device):
+        with pytest.raises(err, match=match):
+            split(base_cfg, overrides)
+
+
+def test_split_overrides_puts_no_column_on_the_device():
+    # float32 base, as on the chip: the grid's float64 columns need a cast
+    with jax.enable_x64(False):
+        ev = ChunkedEvaluator(P, S, C, chunk=64)
+        cols = space_block(SPACE, 0, 50)
+        with jax.transfer_guard_host_to_device("disallow"):
+            batched, static, n = split_overrides(ev.base_cfg, cols)
+            # the guard is live: the old split casts each column on the device
+            with pytest.raises(Exception, match="Disallowed host-to-device transfer"):
+                _split_via_device(ev.base_cfg, cols)
+    assert n == 50 and batched.keys() == cols.keys()
+    assert static.keys() == ev.base_cfg.keys()
+
+
+@pytest.mark.parametrize("dt, x64", [("float32", False), ("float32", True),
+                                     ("float64", True)])
+def test_split_overrides_copies_caller_columns(dt, x64):
+    with jax.enable_x64(x64):
+        base_cfg = {"a": jnp.asarray(0.0, dt), "b": jnp.asarray(0.0, dt)}
+        a = np.linspace(1.0, 2.0, 9, dtype=dt)    # the base's dtype: no cast
+        b = np.linspace(3.0, 4.0, 9)
+        batched, _, _ = split_overrides(base_cfg, {"a": a, "b": b})
+    want = {k: v.copy() for k, v in batched.items()}
+    assert not np.shares_memory(batched["a"], a)
+    a[:] = -1.0
+    b[:] = -1.0
+    for k in want:
+        assert np.array_equal(batched[k], want[k]), k
+
+
+@pytest.mark.parametrize("space", [
+    SPACE,
+    {"pSortMB": [0.25, 1.0, 100.0, 200.0], "pSortFactor": [2.0, 10.0],
+     "pNumReducers": [0.0, 4.0, 16.0]},
+], ids=["valid", "mixed"])
+def test_chunk_topk_unchanged_by_host_casts(space, monkeypatch):
+    import repro.search.evaluator as evaluator
+
+    n = space_size(space)
+    with jax.enable_x64(False):     # float32 base, as on the chip
+        ev = ChunkedEvaluator(P, S, C, chunk=256)
+        cols = space_block(space, 0, n)
+        new = ev.chunk_topk(cols, k=5)
+        monkeypatch.setattr(evaluator, "split_overrides", _split_via_device)
+        old = ev.chunk_topk(cols, k=5)
+    assert 0 < old.n_valid <= n and new.n_valid == old.n_valid
+    for f in ("costs", "idx", "inv_costs", "inv_idx"):
+        a, b = getattr(new, f), getattr(old, f)
+        assert a.dtype == b.dtype and np.array_equal(_bits(a), _bits(b)), f
+    assert new.reason_counts == old.reason_counts
 
 
 # ------------------------------------------------------------------
