@@ -262,7 +262,7 @@ def _build_cluster_rollout():
     names: list[str] = []
 
     def fn(scen):
-        out = _sim_one(scen, 8, True, True, True)
+        out = _sim_one(scen, None, 8, True, True, True)
         names.extend(sorted(out))
         return {k: out[k] for k in sorted(out)}
 
@@ -327,7 +327,7 @@ def _build_cloud_rollout():
     names: list[str] = []
 
     def fn(scen):
-        out = _sim_one(scen, 8, True, True, True, True)
+        out = _sim_one(scen, None, 8, True, True, True, True)
         names.extend(sorted(out))
         return {k: out[k] for k in sorted(out)}
 

@@ -68,7 +68,7 @@ from repro.cluster.sched import ClusterConfig, NodeClass, simulate_workload
 from repro.cluster.vector_sim import (
     POLICIES,
     estimate_steps,
-    pack_trace,
+    pack_traces,
     simulate_batch,
 )
 from repro.cluster.workload import (
@@ -242,9 +242,9 @@ class CloudEvaluator(Evaluator):
             poisson_trace(self.classes, n_jobs, rate=1.0, seed=trace_seed + s)
             for s in range(n_seeds)
         ]
-        packed = [pack_trace(t) for t in self.traces]
-        #: (S, J) per-job constants shared by every scenario
-        self._cols = {k: np.stack([p[k] for p in packed]) for k in packed[0]}
+        #: (S, J) per-job constants and (S, J, P) DAG edges shared by
+        #: every scenario
+        self._cols = pack_traces(self.traces)
         self._base = base
         self._sim = sim
         self.on_demand_price = float(on_demand_price)
@@ -520,9 +520,8 @@ class CloudEvaluator(Evaluator):
             "topo_cross_bw": rep(xbw_s),
             "topo_oversub": rep(osub_s),
         }
-        if "dep" in cols:
-            scen["dep"] = perjob(cols["dep"])
-            scen["dep_kind"] = perjob(cols["dep_kind"])
+        for k in ("dep", "dep_kind"):     # (S, J, P) -> (b*S, J, P)
+            scen[k] = np.tile(cols[k], (b, 1, 1))
         out = simulate_batch(scen, n_steps=estimate_steps(scen),
                              devices=self._devs)
         shp = (b, s)

@@ -10,8 +10,10 @@ exactly like the single-job Hadoop model:
 * ``evaluate`` expands each override row into (row x workload-seed)
   scenarios, rolls them out with the vectorized wave simulator
   (:mod:`repro.cluster.vector_sim`), and aggregates per-trace tail metrics;
-* the cost is ``mean`` or ``p95`` job latency (submit -> finish) averaged
-  over the workload seeds — the capacity-planning objective;
+* the cost is ``mean`` or ``p95`` job latency (submit -> finish), or the
+  ``makespan`` (first arrival -> last finish, the elapsed time of a batch
+  such as a benchmark's throughput test), averaged over the workload
+  seeds — the capacity-planning objective;
 * ``exact_cost`` routes an assignment through the multi-job DES
   (:func:`repro.cluster.sched.simulate_workload`), the trusted reference —
   rows the wave model could not converge (``valid == 0``) are re-costed
@@ -61,12 +63,12 @@ from repro.spec import Axis, ParamSpace, Predicate
 
 from .network import Topology
 from .sched import ClusterConfig, NodeClass, simulate_workload
-from .vector_sim import POLICIES, estimate_steps, pack_trace, simulate_batch
+from .vector_sim import POLICIES, estimate_steps, pack_traces, simulate_batch
 from .workload import JobClass, WorkloadTrace, default_job_classes, poisson_trace, rescale
 
 __all__ = ["ClusterEvaluator", "UnfinishedWorkloadError", "cluster_space"]
 
-_OBJECTIVES = {"mean": "w_meanLat", "p95": "w_p95Lat"}
+_OBJECTIVES = {"mean": "w_meanLat", "p95": "w_p95Lat", "makespan": "w_makespan"}
 
 
 class UnfinishedWorkloadError(ExactCostUnavailable):
@@ -175,7 +177,8 @@ class ClusterEvaluator(Evaluator):
         speculation, node failures.  The wave model does not simulate
         failures; a failure schedule only moves the exact path.
     objective : ``"p95"`` (default — tail latency is what capacity is
-        bought for) or ``"mean"``.
+        bought for), ``"mean"``, or ``"makespan"`` (the whole trace's
+        elapsed time, first arrival to last finish).
     chunk : rows per vectorized call (rounded up to the device count).
     """
 
@@ -203,9 +206,9 @@ class ClusterEvaluator(Evaluator):
             poisson_trace(self.classes, n_jobs, rate=1.0, seed=trace_seed + s)
             for s in range(n_seeds)
         ]
-        packed = [pack_trace(t) for t in self.traces]
-        #: (S, J) per-job constants shared by every scenario
-        self._cols = {k: np.stack([p[k] for p in packed]) for k in packed[0]}
+        #: (S, J) per-job constants and (S, J, P) DAG edges shared by
+        #: every scenario
+        self._cols = pack_traces(self.traces)
         self._objective = objective
         self._base = base
         self._sim = sim
@@ -234,6 +237,11 @@ class ClusterEvaluator(Evaluator):
             else tuple(compat.default_search_devices())
         self.num_devices = len(self._devs)
         self.chunk = -(-max(chunk, 1) // self.num_devices) * self.num_devices
+        # a chunk's DAG edge columns (chunk x S, J, P), row-major like its
+        # scenarios: the same for every row of a trace and every chunk, so
+        # built once, as integers
+        self._edge_cols = {k: np.tile(self._cols[k], (self.chunk, 1, 1))
+                           for k in ("dep", "dep_kind")}
         fast_n, fast_spd = 0, 1.0
         if base.node_classes:
             # the axis space models a two-class fleet: N fast nodes
@@ -374,15 +382,20 @@ class ClusterEvaluator(Evaluator):
         rate = cfg["arrivalRate"]
         vals = []
         for tr in self.traces:
-            res = simulate_workload(rescale(tr, rate), cc, self._sim)
+            run = rescale(tr, rate)
+            res = simulate_workload(run, cc, self._sim)
             if res.n_unfinished:
                 raise UnfinishedWorkloadError(
                     f"{res.n_unfinished}/{len(res.jobs)} jobs never finished "
-                    f"on {cc} — the {self._objective} latency objective is "
+                    f"on {cc} — the {self._objective} objective is "
                     "undefined (inf); inspect WorkloadResult.n_unfinished"
                 )
-            vals.append(res.p95_latency if self._objective == "p95"
-                        else res.mean_latency)
+            if self._objective == "makespan":
+                # the wave model's span: first arrival to last finish
+                vals.append(res.makespan - run.arrivals[0].submit_time)
+            else:
+                vals.append(res.p95_latency if self._objective == "p95"
+                            else res.mean_latency)
         return float(np.mean(vals))
 
     # ---------------- internals ----------------
@@ -468,9 +481,7 @@ class ClusterEvaluator(Evaluator):
             "topo_cross_bw": rep(xbw_s),
             "topo_oversub": rep(osub_s),
         }
-        if "dep" in cols:
-            scen["dep"] = perjob(cols["dep"])
-            scen["dep_kind"] = perjob(cols["dep_kind"])
+        scen.update(self._edge_cols)
         if np.any(fast_s > 0):
             # two class columns, fastest first: (fast fleet, baseline fleet)
             scen["map_slots"] = rep2(np.stack(

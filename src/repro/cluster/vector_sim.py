@@ -52,10 +52,13 @@ classes, Q = capacity queues):
   map_slots (B, C)  red_slots (B, C)  speedup (B, C)
   policy (B,)       slowstart (B,)    queue_frac (B, Q)
 
-**DAG workloads** add ``dep`` / ``dep_kind`` (B, J) columns (default -1 /
-0): job ``j`` arrives once job ``dep[j]`` finishes (kind 0, barrier) or
-finishes its map phase (kind 1, slowstart) — single-parent chains/trees
-only; multi-parent joins go through the DES.  **Topology-aware shuffle**
+**DAG workloads** add ``dep`` / ``dep_kind`` (B, J, P) edge columns
+(default -1 / 0): ``dep[j, p]`` is
+the index of job ``j``'s ``p``-th parent, -1 for none, and job ``j`` is
+held until the latest of its parents' milestones — a parent's finish
+(kind 0, barrier) or its map-phase finish (kind 1, slowstart) — and
+arrives one zero-advance step after the milestone that frees it.  One
+path serves chains, trees and fan-in joins alike.  **Topology-aware shuffle**
 adds ``topo_racks`` / ``topo_cross_bw`` / ``topo_oversub`` (B,) columns
 (default 1 / inf / 1): each reduce wave's shuffle term is divided by the
 rack-incast effective bandwidth
@@ -117,8 +120,8 @@ from repro.obs import current as _obs_current
 from .network import effective_bandwidth
 from .workload import WorkloadTrace, shuffle_full, task_costs
 
-__all__ = ["POLICIES", "latency_quantile", "pack_trace", "estimate_steps",
-           "simulate_batch"]
+__all__ = ["POLICIES", "latency_quantile", "pack_trace", "pack_traces",
+           "estimate_steps", "simulate_batch"]
 
 _EPS = 1e-3          # event-time / task-count slack (durations are >= ~0.1 s)
 _INF = jnp.inf
@@ -129,13 +132,14 @@ POLICIES = ("fifo", "fair", "fair_preempt", "capacity")
 
 
 def pack_trace(trace: WorkloadTrace) -> dict[str, np.ndarray]:
-    """Per-job columns (J,) for one trace.  ``shuffle`` is the all-remote
-    limit (:func:`~repro.cluster.workload.shuffle_full`); multiply by the
+    """Per-job columns (J,) for one trace, and its DAG edges as (J, P)
+    columns.  ``shuffle`` is the all-remote limit
+    (:func:`~repro.cluster.workload.shuffle_full`); multiply by the
     candidate cluster's remote fraction ``(n-1)/n`` before simulating.
     ``queue`` is the job's capacity-scheduler queue: the index of its job
     class name in sorted order (the DES's queue enumeration)."""
     cols = {k: [] for k in ("arrival", "n_maps", "n_reds", "map_cost",
-                            "red_work", "shuffle", "queue", "dep", "dep_kind")}
+                            "red_work", "shuffle", "queue")}
     qidx = {name: i for i, name in
             enumerate(sorted({a.klass.name for a in trace.arrivals}))}
     pos = {a.job_id: i for i, a in enumerate(trace.arrivals)}
@@ -148,19 +152,32 @@ def pack_trace(trace: WorkloadTrace) -> dict[str, np.ndarray]:
         cols["red_work"].append(rc)
         cols["shuffle"].append(shuffle_full(a.klass))
         cols["queue"].append(qidx[a.klass.name])
-        # DAG edge columns: index of the (single) parent, or -1; kind 0 =
-        # barrier, 1 = slowstart.  The wave rollout gates arrival on the
-        # parent's finish / map-finish column, which only expresses one
-        # parent per job — joins stay DES territory.
-        deps = a.deps
-        if len(deps) > 1:
-            raise ValueError(
-                "the wave model supports single-parent DAG jobs; route "
-                f"multi-parent job {a.job_id} through the DES")
-        cols["dep"].append(pos[deps[0][0]] if deps else -1)
-        cols["dep_kind"].append(
-            1.0 if deps and deps[0][1] == "slowstart" else 0.0)
-    return {k: np.asarray(v, dtype=np.float64) for k, v in cols.items()}
+    out = {k: np.asarray(v, dtype=np.float64) for k, v in cols.items()}
+    # DAG edges: P is the trace's most parents of one job (1 for a chain or
+    # no DAG), unused slots -1; kind 0 = barrier, 1 = slowstart.  Indices,
+    # so integer columns: they ride every scenario of the trace unchanged.
+    n_par = max((len(a.deps) for a in trace.arrivals), default=0)
+    dep = np.full((trace.n_jobs, max(n_par, 1)), -1, dtype=np.int32)
+    kind = np.zeros(dep.shape, dtype=np.int8)
+    for j, a in enumerate(trace.arrivals):
+        for p, (parent, edge) in enumerate(a.deps):
+            dep[j, p] = pos[parent]
+            kind[j, p] = edge == "slowstart"
+    out["dep"], out["dep_kind"] = dep, kind
+    return out
+
+
+def pack_traces(traces) -> dict[str, np.ndarray]:
+    """:func:`pack_trace` of each trace, stacked: (S, J) job columns and
+    (S, J, P) edge columns, P the largest of the traces' (every trace
+    needs the same job count)."""
+    packed = [pack_trace(t) for t in traces]
+    n_par = max(p["dep"].shape[1] for p in packed)
+    for p in packed:
+        pad = ((0, 0), (0, n_par - p["dep"].shape[1]))
+        p["dep"] = np.pad(p["dep"], pad, constant_values=-1)
+        p["dep_kind"] = np.pad(p["dep_kind"], pad)
+    return {k: np.stack([p[k] for p in packed]) for k in packed[0]}
 
 
 def estimate_steps(scen: Mapping[str, np.ndarray], *, margin: float = 2.0
@@ -168,29 +185,36 @@ def estimate_steps(scen: Mapping[str, np.ndarray], *, margin: float = 2.0
     """Step *cap* covering every wave event, rounded up to a power of two
     so compile count stays bounded across workloads.  The rollout is a
     ``while_loop`` that stops at the batch's last event, so a generous cap
-    costs nothing; ``margin`` absorbs wave fragmentation under contention
-    (doubled when preemptive rows are present — kills re-fragment waves),
-    and truncation at the cap is detected, not silent (``converged``)."""
+    costs nothing, and truncation at the cap is detected, not silent
+    (``converged``).
+
+    Without preemption the cap is a bound: every step retires a wave
+    bucket of at least one whole task, admits a job or releases one, so a
+    row's tasks plus twice its jobs bound its steps.  Fair sharing among
+    many concurrent jobs needs that room: each job's waves run on its
+    slice of the slots, not the whole pool.  Preemptive rows re-run killed
+    tasks, so theirs is an estimate: the waves on the whole pool times
+    ``margin``, doubled (kills re-fragment waves)."""
     def total(key):
         a = np.asarray(scen[key], dtype=np.float64)
         return np.maximum(a.sum(axis=-1) if a.ndim == 2 else a, 1.0)
+    n_maps, n_reds = np.asarray(scen["n_maps"]), np.asarray(scen["n_reds"])
     ms, rs = total("map_slots"), total("red_slots")
-    waves = (np.ceil(scen["n_maps"] / ms[:, None]).sum(axis=1)
-             + np.ceil(scen["n_reds"] / rs[:, None]).sum(axis=1))
-    pol = np.asarray(scen.get("policy", scen.get("fair", 0.0)))
-    if np.any(pol >= 2):
-        margin = margin * 2.0
+    waves = (np.ceil(n_maps / ms[:, None]).sum(axis=1)
+             + np.ceil(n_reds / rs[:, None]).sum(axis=1))
+    pol = np.broadcast_to(np.asarray(scen.get("policy", scen.get("fair", 0.0))),
+                          waves.shape)
+    preempt = pol > 1.5
     n_jobs = scen["arrival"].shape[-1]
-    est = int(np.max(waves) * margin) + n_jobs + 8
-    if np.any(np.asarray(scen.get("dep", -1.0)) >= 0):
-        # each DAG release costs one zero-advance step (the child arrives
-        # one step after its parent's milestone lands)
-        est += n_jobs
+    est = int(np.max(waves, where=preempt, initial=0) * margin * 2.0)
+    est = max(est, int(np.max((n_maps + n_reds).sum(axis=1), where=~preempt,
+                              initial=0)))
+    # one event per arrival and one zero-advance step per DAG release
+    est += 2 * n_jobs + 8
     if (np.any(np.asarray(scen.get("autoscale", 0.0)) > 0.5)
             or np.any(np.asarray(scen.get("extra_map_slots", 0.0)) > 0)):
         # elastic rows add provision/teardown events (the queue policy can
-        # cycle once per burst) — waves above were counted on base slots
-        # only, so this is the only extra headroom needed
+        # cycle once per burst)
         est += n_jobs + 8
     return 1 << (est - 1).bit_length()
 
@@ -299,7 +323,7 @@ def latency_quantile(values, q: float):
 # --------------------------------------------------------------------------
 
 
-def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
+def _sim_one(s: dict, edges, n_steps: int, with_fair: bool, with_preempt: bool,
              with_capacity: bool, with_cloud: bool = False,
              with_dag: bool = False, with_topo: bool = False) -> dict:
     arrival = s["arrival"]
@@ -350,15 +374,31 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
         onehot = (jnp.round(s["queue"])[:, None]
                   == jnp.arange(qf.shape[0])[None, :]).astype(arrival.dtype)
     if with_dag:
-        # single-parent DAG edges: job j's arrival is gated on dep[j]'s
-        # finish (barrier) or map-finish (slowstart) column
-        dep = jnp.round(s["dep"]).astype(jnp.int32)
-        dep_slow = s["dep_kind"] > 0.5
-        pidx = jnp.clip(dep, 0, J - 1)
+        # DAG edges: the batch's distinct (J, P) edge tables, shared by its
+        # lanes (``edges``, sources into [finish, map-finish] by position,
+        # -1 none), and this lane's table.  A job is freed once every
+        # parent's milestone has landed, counted as a product of the
+        # table's 0/1 adjacency with the lane's milestones-landed vector:
+        # one matmul over the lanes a step, where a per-lane gather of
+        # parents runs serially on the TPU.  It arrives at the latest
+        # parent's milestone, the time of the step that frees it.
+        n_tab = edges.shape[0]
+        # 0/1 entries and counts of a few parents are exact in any float
+        # matmul; int8 runs slower on the chip
+        adj = jax.nn.one_hot(edges, 2 * J, dtype=jnp.float32).sum(2)     # (U, J, 2J)
+        mine = jnp.arange(n_tab) == s["edge_id"]                         # (U,)
+        n_par = jnp.where(mine[:, None], (edges >= 0).sum(-1), 0).sum(0)  # (J,)
 
-        def eligible_at(map_fin_col, fin_col):
-            parent_t = jnp.where(dep_slow, map_fin_col[pidx], fin_col[pidx])
-            return jnp.maximum(arrival, jnp.where(dep >= 0, parent_t, -_INF))
+        def freed_by(map_fin_col, fin_col):
+            landed = jnp.concatenate([jnp.isfinite(fin_col),
+                                      jnp.isfinite(map_fin_col)]).astype(jnp.float32)
+            cnt = jnp.einsum("ujk,k->uj", adj, landed,
+                             preferred_element_type=jnp.float32)
+            return jnp.where(mine[:, None], cnt, 0.0).sum(0) >= n_par
+
+        def release_at(release, map_fin_col, fin_col, t):
+            return jnp.where((release == _INF) & freed_by(map_fin_col, fin_col),
+                             t, release)
     if with_topo:
         def shuffle_eff(n_flows):
             # per-rack incast contention: concurrent transfers share the
@@ -408,13 +448,17 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
             x_t_on=jnp.asarray(_INF, arrival.dtype),
             x_billed=jnp.zeros((), arrival.dtype),
         )
+    if with_dag:
+        # release time per job (-inf: no parents), and release steps
+        state0.update(release=jnp.where(n_par > 0, _INF, -_INF).astype(arrival.dtype),
+                      rel=jnp.asarray(0, jnp.int32))
 
     def step(st):
         t = st["t"]
         if with_dag:
             # releases land on the previous state's milestones, so a child
             # released at this instant arrives one (zero-advance) step later
-            eligible = eligible_at(st["map_fin"], st["fin"])
+            eligible = jnp.maximum(arrival, st["release"])
         else:
             eligible = arrival
         arrived = eligible <= t + _EPS
@@ -579,9 +623,12 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
             x_t_on = jnp.where(drop, _INF, x_t_on)
 
         if with_dag:
-            # re-read eligibility off the UPDATED milestones so a future
-            # release is a scheduled event, not a missed one
-            elig_next = eligible_at(map_fin, fin)
+            # re-read eligibility off the UPDATED milestones: a job freed
+            # by this step's milestones arrives at this same instant, one
+            # zero-advance step later; a future release is a scheduled event
+            release = release_at(st["release"], map_fin, fin, t)
+            elig_next = jnp.maximum(arrival, release)
+            released = ((elig_next <= t + _EPS) & ~arrived).any()
         else:
             elig_next = arrival
         t_next = jnp.minimum(
@@ -589,6 +636,8 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
             jnp.minimum(m_end.min(), r_end.min()))
         if with_cloud:
             t_next = jnp.minimum(t_next, x_at)
+        if with_dag:
+            t_next = jnp.where(released, t, t_next)
         t_new = jnp.where(jnp.isfinite(t_next), t_next, t)
 
         nxt = dict(k=st["k"] + 1, t=t_new, m_todo=m_todo, m_run=m_run,
@@ -597,6 +646,9 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
                    red_launch=red_launch, map_fin=map_fin, fin=fin)
         if with_cloud:
             nxt.update(x_on=x_on, x_at=x_at, x_t_on=x_t_on, x_billed=x_billed)
+        if with_dag:
+            nxt.update(release=release,
+                       rel=st["rel"] + released.astype(jnp.int32))
         return nxt
 
     def cont(st):
@@ -610,7 +662,7 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
         # a DAG child's service clock starts at its release (the DES sets
         # submit_time the same way); double-where: an unreleased child has
         # an infinite release, and inf - inf is the nan this guards against
-        submit = eligible_at(st["map_fin"], st["fin"])
+        submit = jnp.maximum(arrival, st["release"])
         sub_safe = jnp.where(jnp.isfinite(submit), submit, 0.0)
         latency = jnp.where(jnp.isfinite(submit), fin - sub_safe, _INF)
     else:
@@ -651,6 +703,8 @@ def _sim_one(s: dict, n_steps: int, with_fair: bool, with_preempt: bool,
         billed = st["x_billed"] + jnp.where(x_open, _quantize(ep, x_quant),
                                             0.0)
         out["extra_billed_s"] = jnp.where(converged, billed, jnp.inf)
+    if with_dag:
+        out["release_steps"] = st["rel"]    # zero-advance release steps
     return out
 
 
@@ -660,13 +714,14 @@ def _compiled(devs: tuple, n_steps: int, with_fair: bool, with_preempt: bool,
               with_dag: bool = False, with_topo: bool = False):
     mesh = compat.make_mesh(list(devs), axis="search")
 
-    def per_device(scen):
+    def per_device(scen, edges):
         return jax.vmap(lambda s: _sim_one(
-            s, n_steps, with_fair, with_preempt, with_capacity,
+            s, edges, n_steps, with_fair, with_preempt, with_capacity,
             with_cloud, with_dag, with_topo))(scen)
 
+    # the scenarios are sharded over the devices, the edge tables replicated
     return jax.jit(compat.shard_map(
-        per_device, mesh=mesh, in_specs=(P("search"),),
+        per_device, mesh=mesh, in_specs=(P("search"), P()),
         out_specs=P("search"), check_vma=False,
     ))
 
@@ -708,9 +763,9 @@ def _normalize(scen: Mapping[str, np.ndarray]) -> dict[str, np.ndarray]:
     # DAG / topology columns: defaults are the flat no-dependency network,
     # so legacy batches compile the same lean kernels (flag detection below)
     if "dep" not in arrs:
-        arrs["dep"] = np.full(arrs["arrival"].shape, -1.0)
+        arrs["dep"] = np.full(arrs["arrival"].shape + (1,), -1, dtype=np.int32)
     if "dep_kind" not in arrs:
-        arrs["dep_kind"] = np.zeros(arrs["arrival"].shape, dtype=np.float64)
+        arrs["dep_kind"] = np.zeros(arrs["dep"].shape, dtype=np.int8)
     if "topo_racks" not in arrs:
         arrs["topo_racks"] = np.ones(b, dtype=np.float64)
     if "topo_cross_bw" not in arrs:
@@ -738,10 +793,12 @@ def _normalize(scen: Mapping[str, np.ndarray]) -> dict[str, np.ndarray]:
 
 
 def _prepare(scen: Mapping[str, np.ndarray], n_steps: int | None,
-             n_devs: int) -> tuple[dict, int, int, tuple]:
-    """The normalized batch padded (edge-replicated) to ``n_devs``, its
-    scenario count before padding, and the static compile keys of
-    :func:`_compiled`: the step cap and the kernel flags."""
+             n_devs: int) -> tuple[dict, np.ndarray, int, int, tuple]:
+    """The normalized batch padded (edge-replicated) to ``n_devs``, with its
+    DAG edges as the distinct (U, J, P) edge tables and each scenario's
+    ``edge_id`` into them; its scenario count before padding; and the
+    static compile keys of :func:`_compiled`: the step cap and the kernel
+    flags."""
     if n_steps is None:
         n_steps = estimate_steps(scen)
     arrs = _normalize(scen)
@@ -750,6 +807,24 @@ def _prepare(scen: Mapping[str, np.ndarray], n_steps: int | None,
     if pad:
         arrs = {k: np.concatenate([v, np.repeat(v[-1:], pad, axis=0)])
                 for k, v in arrs.items()}
+    dep = np.asarray(arrs.pop("dep"))
+    if dep.dtype.kind == "f":
+        dep = np.rint(dep)
+    dep = dep.astype(np.int32, copy=False)
+    kind = arrs.pop("dep_kind")
+    with_dag = bool(np.any(dep >= 0))
+    edge_id = np.zeros(len(dep), dtype=np.int32)
+    if with_dag:
+        # each edge's source in [finish, map-finish]; the scenarios of one
+        # trace repeat its table, so the device gets each distinct table once
+        src = np.where(dep >= 0, dep + dep.shape[1] * (kind != 0), -1).astype(np.int32)
+        ids: dict[bytes, int] = {}
+        edge_id[:] = [ids.setdefault(r.tobytes(), len(ids)) for r in src]
+        edges = src[np.unique(edge_id, return_index=True)[1]]
+    else:
+        # one empty table, without a pass over the batch's edge columns
+        edges = np.full((1,) + dep.shape[1:], -1, dtype=np.int32)
+    arrs["edge_id"] = edge_id
     pol = arrs["policy"]
     with_fair = bool(np.any(pol > 0.5))
     with_preempt = bool(np.any(pol > 1.5))
@@ -758,13 +833,12 @@ def _prepare(scen: Mapping[str, np.ndarray], n_steps: int | None,
                       or np.any(arrs["extra_map_slots"] > 0)
                       or np.any(arrs["extra_red_slots"] > 0)
                       or np.any(arrs["reclaim_rate"] > 0))
-    with_dag = bool(np.any(arrs["dep"] >= 0))
     with_topo = bool(np.any(
         (arrs["topo_racks"] > 1.5)
         & np.isfinite(arrs["topo_cross_bw"]
                       / np.maximum(arrs["topo_oversub"], 1.0))))
-    return arrs, b, n_steps, (with_fair, with_preempt, with_capacity,
-                              with_cloud, with_dag, with_topo)
+    return arrs, edges, b, n_steps, (with_fair, with_preempt, with_capacity,
+                                     with_cloud, with_dag, with_topo)
 
 
 def simulate_batch(
@@ -784,14 +858,16 @@ def simulate_batch(
     ob = _obs_current()
     with ob.span("vector_sim.simulate_batch"):
         with ob.span("vector_sim.prepare"):
-            arrs, b, n_steps, flags = _prepare(scen, n_steps, len(devs))
+            arrs, edges, b, n_steps, flags = _prepare(scen, n_steps, len(devs))
         with ob.span("vector_sim.dispatch", scenarios=b, n_steps=n_steps):
-            out = _compiled(devs, n_steps, *flags)(arrs)
+            out = _compiled(devs, n_steps, *flags)(arrs, edges)
         with ob.span("vector_sim.fetch"):
             steps = out.pop("steps")
+            rel = out.pop("release_steps", None)
             host = {k: np.asarray(v)[:b] for k, v in out.items()}
             if ob.enabled:
                 steps = np.asarray(steps)
+                rel = 0 if rel is None else int(np.asarray(rel).sum())
     if ob.enabled:
         reg = ob.registry
         reg.counter("vector_sim.batches").inc()
@@ -804,4 +880,6 @@ def simulate_batch(
         reg.counter("vector_sim.lane_steps").inc(int(steps.sum()))
         reg.counter("vector_sim.loop_steps").inc(
             per_dev.shape[1] * int(per_dev.max(axis=1).sum()))
+        # lane-steps in which the clock stood still to release a DAG job
+        reg.counter("vector_sim.release_steps").inc(rel)
     return host

@@ -376,10 +376,10 @@ def dag_trace(
     """Expand a :class:`StageDag` into a dependency-carrying trace.
 
     Each instance contributes ``len(dag.stages)`` arrivals sharing one
-    submit time; non-root stages carry ``deps`` edges so the DES (and,
-    single-parent, the wave model) holds them until their parents release.
-    Stage job-ids follow topological order, so every parent id is lower
-    than its children's — the order :func:`pack_trace` requires.
+    submit time; non-root stages carry ``deps`` edges, one per parent, so
+    the DES and the wave model hold them until the latest parent releases
+    them.  Stage job-ids follow topological order, so every parent id is
+    lower than its children's.
     """
     if n_instances < 1:
         raise ValueError(f"n_instances must be >= 1, got {n_instances}")

@@ -230,6 +230,36 @@ def test_estimate_steps_power_of_two():
     assert n & (n - 1) == 0 and n > 0
 
 
+def _many_jobs_at_once(n_jobs, n_maps, slots, policy):
+    """``n_jobs`` map-only jobs of ``n_maps`` tasks each, all submitted at 0
+    on ``slots`` map slots, with seeded task times of 1 to 3 s."""
+    rng = np.random.default_rng(0)
+    zeros = np.zeros((1, n_jobs))
+    return {"arrival": zeros, "n_maps": np.full((1, n_jobs), float(n_maps)),
+            "n_reds": zeros, "map_cost": rng.uniform(1.0, 3.0, (1, n_jobs)),
+            "red_work": zeros, "shuffle": zeros, "map_slots": np.asarray([float(slots)]),
+            "red_slots": np.asarray([4.0]), "policy": np.asarray([float(policy)]),
+            "slowstart": np.ones(1)}
+
+
+@pytest.mark.parametrize("policy", [0, 1])
+@pytest.mark.parametrize("n_jobs,n_maps,slots", [(8, 100, 8), (14, 400, 16)])
+def test_estimate_steps_covers_fair_sharing_among_many_jobs(n_jobs, n_maps, slots, policy):
+    # fair shares split each job's waves over a slice of the slots: these
+    # rows take more steps than twice their waves on the whole pool (the
+    # cap before), which truncated them
+    scen = _many_jobs_at_once(n_jobs, n_maps, slots, policy)
+    waves = n_jobs * -(-n_maps // slots)
+    old_cap = 1 << (2 * waves + n_jobs + 8 - 1).bit_length()
+    out = simulate_batch(scen)
+    assert out["converged"][0] == 1.0
+    ref = simulate_batch(scen, n_steps=1 << 16)
+    np.testing.assert_array_equal(out["finish"], ref["finish"])
+    assert estimate_steps(scen) >= n_jobs * n_maps
+    if policy == 1:
+        assert simulate_batch(scen, n_steps=old_cap)["converged"][0] == 0.0
+
+
 # ------------------------------------------------------------------ planner
 
 

@@ -38,6 +38,7 @@ from repro.cluster.network import flow_rates, max_min_rates
 from repro.cluster.vector_sim import pack_trace, simulate_batch
 from repro.cluster.workload import (
     JobArrival,
+    JobClass,
     WorkloadTrace,
     _job_model_cached,
     stage_output_bytes,
@@ -283,18 +284,101 @@ def test_dag_releases_at_barrier_and_slowstart():
     assert js[2].submit_time < js[1].finish
 
 
-def test_wave_rejects_multi_parent_dags():
-    wc = BY_NAME["wordcount"]
-    tr = WorkloadTrace((
-        JobArrival(0, wc, 0.0),
-        JobArrival(1, wc, 0.0),
-        JobArrival(2, wc, 0.0, deps=((0, "barrier"), (1, "barrier"))),
-    ))
-    with pytest.raises(ValueError, match="single-parent"):
-        pack_trace(tr)
-    # the DES handles the same trace fine (fan-in joins are its territory)
-    res = simulate_workload(tr, ClusterConfig(num_nodes=8), SimConfig(seed=0))
-    assert res.n_unfinished == 0
+def _wave_vs_des(trace, nodes):
+    """Per-job finish times of the wave model and of the DES, in the
+    trace's arrival order, on one contention-free cluster."""
+    des = simulate_workload(trace, ClusterConfig(num_nodes=nodes),
+                            SimConfig(seed=0))
+    by_id = {js.job_id: js for js in des.jobs}
+    out = _wave_one(trace, nodes=nodes)
+    assert out["converged"][0] == 1.0 and des.n_unfinished == 0
+    want = [by_id[a.job_id].finish for a in trace.arrivals]
+    return out["finish"][0], np.asarray(want)
+
+
+_FAN_IN = {
+    # (stages, edges): a diamond, a 3-parent join, and both edge kinds
+    # feeding one join
+    "diamond": (("wordcount", "sort", "filter", "aggregate"),
+                [(0, 1), (0, 2), (1, 3), (2, 3)]),
+    "three_parent_join": (("sort", "wordcount", "filter", "aggregate"),
+                          [(0, 3), (1, 3), (2, 3)]),
+    "mixed_edges": (("wordcount", "sort", "filter", "aggregate"),
+                    [(0, 1, "slowstart"), (0, 2), (1, 3, "slowstart"),
+                     (2, 3)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FAN_IN))
+def test_wave_fan_in_dag_tracks_des(name):
+    # two interleaved instances on a cluster with a slot for every task:
+    # no wave ever waits, so the wave model is exact per job
+    stages, edges = _FAN_IN[name]
+    dag = dag_from_templates(name, [BY_NAME[n] for n in stages], edges)
+    tr = dag_trace(dag, n_instances=2, inter_arrival=3.0)
+    assert pack_trace(tr)["dep"].shape == (
+        tr.n_jobs, max(len(dag.parents_of(i)) for i in range(len(stages))))
+    got, want = _wave_vs_des(tr, nodes=256)
+    np.testing.assert_allclose(got, want, rtol=1e-3)
+
+
+@pytest.mark.parametrize("late", [1, 2])
+def test_wave_join_waits_for_its_latest_parent(late):
+    # the join's parents finish at different times; the latest one is
+    # listed second or third, so gating on the first parent releases the
+    # join too early
+    sort, wc = BY_NAME["sort"], BY_NAME["wordcount"]
+    big = JobClass(name="big", stats=sort.stats, costs=sort.costs,
+                   params=sort.params.replace(pNumMappers=64))
+    parents = [wc, wc, wc]
+    parents[late] = big
+    deps = tuple((i, "barrier") for i in range(3))
+    tr = WorkloadTrace(tuple(JobArrival(i, jc, 0.0)
+                             for i, jc in enumerate(parents))
+                       + (JobArrival(3, sort, 0.0, deps=deps),))
+    assert pack_trace(tr)["dep"].tolist() == [[-1] * 3] * 3 + [[0, 1, 2]]
+    out = _wave_one(tr, nodes=64)
+    fin = out["finish"][0]
+    release = fin[3] - out["latency"][0][3]
+    assert fin[late] == fin[:3].max() > fin[:3].min()
+    np.testing.assert_allclose(release, fin[late], rtol=1e-6)
+    got, want = _wave_vs_des(tr, nodes=64)
+    np.testing.assert_allclose(got, want, rtol=1e-3)
+
+
+def test_release_steps_count_one_step_per_release():
+    from repro.obs import observe
+
+    dag = dag_from_templates(
+        "chain3", [BY_NAME["sort"]] * 3, [(0, 1), (1, 2, "slowstart")])
+    tr = dag_trace(dag)
+    with observe() as ob:
+        out = _wave_one(tr, nodes=8)
+    assert out["converged"][0] == 1.0
+    reg = ob.registry
+    assert reg.counter("vector_sim.release_steps").value == 2
+    assert reg.counter("vector_sim.lane_steps").value > 2
+    # a batch with no edges keeps the lean kernel and counts none
+    with observe() as ob:
+        _wave_one(WorkloadTrace((JobArrival(0, BY_NAME["sort"], 0.0),)),
+                  nodes=8)
+    assert ob.registry.counter("vector_sim.release_steps").value == 0
+
+
+def test_cluster_evaluator_searches_fan_in_dags_by_makespan():
+    from repro.cluster import ClusterEvaluator
+
+    stages, edges = _FAN_IN["three_parent_join"]
+    dag = dag_from_templates("join", [BY_NAME[n] for n in stages], edges)
+    tr = dag_trace(dag, n_instances=2, inter_arrival=3.0)
+    ev = ClusterEvaluator(traces=[tr], objective="makespan", chunk=4)
+    assert ev.cost_key == "w_makespan"
+    res = ev.evaluate({"pNumNodes": np.asarray([256.0, 4.0])})
+    assert res.outputs["valid"].tolist() == [1.0, 1.0]
+    # contention-free row: the DES's makespan, first arrival to last finish
+    exact = ev.exact_cost({"pNumNodes": 256.0})
+    np.testing.assert_allclose(res.outputs["w_makespan"][0], exact, rtol=1e-3)
+    assert res.outputs["w_makespan"][1] > res.outputs["w_makespan"][0]
 
 
 def test_wave_dag_chain_tracks_des():

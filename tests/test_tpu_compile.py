@@ -5,7 +5,8 @@ is described rather than attached.  These tests lower and compile, for one
 described v5e chip in float32, what ``chip_smoke.py`` runs on a real one:
 
 * the ``ChunkedEvaluator`` top-k body and full-output body at chunk 8192;
-* the planner's wave rollout (``vector_sim``) at 2048 scenarios x 64 jobs;
+* the planner's wave rollout (``vector_sim``) at 2048 scenarios x 64 jobs,
+  and with fan-in DAG edges on racks at 512 scenarios x 658 jobs;
 * the ``seg_combine`` Pallas kernel at three shapes.
 
 Nothing runs, so these say nothing about results or times; they catch what
@@ -107,7 +108,7 @@ def test_whatif_evaluate_body_compiles_for_v5e(topo, one_chip):
 
 def test_wave_rollout_compiles_for_v5e(topo, one_chip):
     from repro.cluster import default_job_classes, pack_trace, poisson_trace
-    from repro.cluster.vector_sim import _compiled, _normalize, estimate_steps
+    from repro.cluster.vector_sim import _compiled, _prepare
 
     b = 2048
     cols = pack_trace(poisson_trace(default_job_classes(), 64, seed=3))
@@ -122,12 +123,49 @@ def test_wave_rollout_compiles_for_v5e(topo, one_chip):
         "slowstart": np.full(b, 0.05),
         "map_slots": nodes * 2.0, "red_slots": nodes * 2.0,
     }
-    arrs = {k: _shape(v.astype(np.float32), one_chip)
-            for k, v in _normalize(scen).items()}
-    fn = _compiled((topo.devices[0],), estimate_steps(scen), True, False,
-                   False)
-    compiled = fn.lower(arrs).compile()
+    arrs, edges, _, n_steps, flags = _prepare(scen, None, 1)
+    arrs = {k: _shape(v.astype(np.float32) if v.dtype == np.float64 else v, one_chip)
+            for k, v in arrs.items()}
+    assert flags == (True, False, False, False, False, False)
+    fn = _compiled((topo.devices[0],), n_steps, *flags)
+    compiled = fn.lower(arrs, _shape(edges, one_chip)).compile()
     assert compiled.out_info["p95_latency"].shape == (b,)
+
+
+def test_wave_rollout_fan_in_dag_on_racks_compiles_for_v5e(topo, one_chip):
+    # the Hive TPC-H planner chunk: 256 rows x 2 traces of 658 jobs, up to
+    # three parents a job, FIFO and fair rows on racked clusters
+    from repro.cluster import default_job_classes, pack_trace, poisson_trace
+    from repro.cluster.vector_sim import _compiled, _prepare
+
+    b, j, p = 512, 658, 3
+    cols = pack_trace(poisson_trace(default_job_classes(), j, seed=3))
+    tile = lambda a: np.tile(a, (b, 1))          # noqa: E731
+    nodes = np.full(b, 20.0)
+    dep = np.full((b, j, p), -1, dtype=np.int32)
+    dep[:, 1:, 0] = np.arange(j - 1)
+    dep[:, 3:, 1:] = np.arange(j - 3)[:, None] + np.asarray([1, 2])
+    scen = {
+        "arrival": np.zeros((b, j)),
+        "n_maps": tile(cols["n_maps"]), "n_reds": tile(cols["n_reds"]),
+        "map_cost": tile(cols["map_cost"]), "red_work": tile(cols["red_work"]),
+        "shuffle": tile(cols["shuffle"]) * ((nodes - 1.0) / nodes)[:, None],
+        "policy": np.repeat([0.0, 1.0], b // 2),
+        "slowstart": np.full(b, 0.05),
+        "map_slots": nodes * 4.0, "red_slots": nodes * 2.0,
+        "dep": dep, "dep_kind": np.zeros((b, j, p), dtype=np.int8),
+        "topo_racks": np.full(b, 16.0), "topo_cross_bw": np.full(b, 40.0),
+        "topo_oversub": np.full(b, 10.0),
+    }
+    arrs, edges, _, n_steps, flags = _prepare(scen, None, 1)
+    assert flags == (True, False, False, False, True, True)    # fair, DAG, racks
+    assert edges.shape == (1, j, p)                 # every lane shares one table
+    arrs = {k: _shape(v.astype(np.float32) if v.dtype == np.float64 else v, one_chip)
+            for k, v in arrs.items()}
+    fn = _compiled((topo.devices[0],), n_steps, *flags)
+    compiled = fn.lower(arrs, _shape(edges, one_chip)).compile()
+    assert compiled.out_info["makespan"].shape == (b,)
+    assert compiled.out_info["release_steps"].dtype == jnp.int32
 
 
 @pytest.mark.parametrize("n, d, parts", [
