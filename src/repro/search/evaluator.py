@@ -16,6 +16,9 @@ device.  :class:`ChunkedEvaluator` replaces it with a streaming design:
   unchunked single-device path within :data:`CROSS_PROGRAM_MAX_ULP`, with
   equal ``valid`` flags and top-k order (asserted by tests and
   ``benchmarks/bench_whatif``); one executable is bit-for-bit repeatable.
+* **One copy per chunk** — ``evaluate`` stacks the model program's output
+  columns on the device, one ``(columns, chunk)`` array per dtype, and
+  brings each to the host in one transfer, not one per column.
 * **On-device top-k** — ``chunk_topk`` reduces each chunk to its ``k`` best
   (and ``k`` best *invalid*) candidates on device, so a 10^6-config search
   transfers k values per chunk to the host instead of the whole grid.
@@ -362,6 +365,21 @@ def evaluate_unchunked(
     return {k: np.asarray(v) for k, v in out.items()}
 
 
+def _pack_layout(out: Mapping[str, Any]) -> tuple[tuple[str, ...], ...]:
+    """The model program's output keys, sorted, grouped by dtype (groups in
+    order of their first key): the row order of :func:`_stack_groups`."""
+    groups: dict[Any, list[str]] = {}
+    for k in sorted(out):
+        groups.setdefault(out[k].dtype, []).append(k)
+    return tuple(tuple(keys) for keys in groups.values())
+
+
+def _stack_groups(groups):
+    """Each group of same-dtype ``(chunk,)`` columns as one ``(C, chunk)``
+    array, a column per row: one device-to-host copy per group."""
+    return [jnp.stack(cols) for cols in groups]
+
+
 @functools.lru_cache(maxsize=None)
 def _unchunked_jit(model_fn):
     @jax.jit
@@ -410,6 +428,7 @@ class ChunkedEvaluator(Evaluator):
 
         body = self._sharded_body()
         self._eval_fn = jax.jit(body)
+        self._pack_fn = jax.jit(_stack_groups)
         self._topk_fn = jax.jit(
             functools.partial(self._topk_body, body), static_argnames=("k",)
         )
@@ -491,21 +510,29 @@ class ChunkedEvaluator(Evaluator):
         with ob.span("evaluator.prepare"):
             batched, static, n = self._split(overrides)
         out_blocks: dict[str, list[np.ndarray]] = {}
+        layout = None
         with ob.span("evaluator.evaluate", rows=n):
             for start in range(0, n, self.chunk):
                 stop = min(start + self.chunk, n)
                 cols, _ = self._pad(batched, start, stop)
                 with ob.span("evaluator.dispatch"):
                     out = self._eval_fn(cols, static)
+                layout = layout or _pack_layout(out)
                 with ob.span("evaluator.fetch"):
-                    host = {k: np.asarray(v) for k, v in out.items()}
+                    # one copy per output dtype, not one per column.  The
+                    # stack is a program of its own: inside the model's jit
+                    # it moved XLA's fusions, and some columns by an ulp
+                    packed = [np.asarray(a) for a in self._pack_fn(
+                        [[out[k] for k in keys] for keys in layout])]
                 if ob.enabled:
                     reg = ob.registry
                     reg.counter("evaluator.chunks").inc()
+                    reg.counter("evaluator.d2h_copies").inc(len(packed))
                     reg.counter("evaluator.d2h_bytes").inc(
-                        sum(v.nbytes for v in host.values()))
-                for k, v in host.items():
-                    out_blocks.setdefault(k, []).append(v[: stop - start])
+                        sum(a.nbytes for a in packed))
+                for keys, block in zip(layout, packed):
+                    for k, row in zip(keys, block):
+                        out_blocks.setdefault(k, []).append(row[: stop - start])
         if ob.enabled:
             padded = -(-n // self.chunk) * self.chunk - n
             ob.registry.counter("evaluator.rows").inc(n)

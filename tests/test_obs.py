@@ -478,8 +478,13 @@ def test_evaluator_spans_split_each_chunk_and_count_fetched_bytes():
     rows = {"pSortMB": np.linspace(16.0, 400.0, 150)}      # 3 chunks
     with observe() as ob:
         res = ev.evaluate(rows)
+        copies = ob.registry.counter("evaluator.d2h_copies").value
         ev.chunk_topk({"pSortMB": rows["pSortMB"][:40]}, 5)
     reg = ob.registry
+    # one packed copy per chunk (every output is float); the top-k path
+    # counts none
+    assert copies == 3
+    assert reg.counter("evaluator.d2h_copies").value == 3
     count = {n: reg.histogram(f"evaluator.{n}_s").count
              for n in ("prepare", "evaluate", "dispatch", "fetch", "chunk_topk")}
     # evaluate: one split, 3 chunks; chunk_topk: one of each

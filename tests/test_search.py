@@ -4,6 +4,8 @@ Covers the contract the subsystem was built around:
 * chunked+sharded evaluation is bit-for-bit identical to the seed's
   unchunked single-device ``jit(vmap(...))`` path;
 * padding at non-divisible batch sizes changes nothing;
+* the one packed copy per chunk and dtype returns the bits, keys and dtypes
+  of copying each output column on its own;
 * a fixed chunk size means ONE compile across arbitrary grid sizes;
 * streamed on-device top-k agrees with a numpy argsort oracle;
 * an all-invalid grid raises from ``best()`` but the search path routes
@@ -24,6 +26,7 @@ import pytest
 
 from repro.core.hadoop import CostFactors, HadoopParams, MiB, ProfileStats
 from repro.core.whatif import evaluate_grid, evaluate_product_grid
+from repro.obs import observe
 from repro.search import (
     ChunkedEvaluator,
     InvalidGridError,
@@ -77,6 +80,71 @@ def test_chunked_matches_unchunked_bit_for_bit():
                                     evaluator=ChunkedEvaluator(P, S, C, chunk=chunk))
         assert res.total_cost.shape == ref.shape
         assert np.array_equal(res.total_cost, ref), f"chunk={chunk}"
+
+
+def _per_column_fetch(ev, overrides):
+    """Every output column of ``ev``'s model program, chunk by chunk, each
+    copied to the host on its own: what ``evaluate`` fetched before it
+    packed the columns into one copy per dtype."""
+    batched, static, n = ev._split(overrides)
+    blocks = {}
+    for start in range(0, n, ev.chunk):
+        stop = min(start + ev.chunk, n)
+        cols, _ = ev._pad(batched, start, stop)
+        for k, v in ev._eval_fn(cols, static).items():
+            blocks.setdefault(k, []).append(np.asarray(v)[: stop - start])
+    return {k: np.concatenate(v) for k, v in blocks.items()}
+
+
+def _bits(a):
+    return a.view(f"u{a.itemsize}")
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["float32", "float64"])
+@pytest.mark.parametrize("scalar", [False, True], ids=["batched", "scalar"])
+def test_evaluate_matches_per_column_fetch_bit_for_bit(x64, scalar):
+    with jax.enable_x64(x64):
+        ev = ChunkedEvaluator(P, S, C, chunk=32)
+        n = 2 * ev.chunk + 7                      # three chunks, ragged tail
+        rng = np.random.default_rng(5)
+        ov = {"pSortMB": rng.choice(SPACE["pSortMB"], n),
+              "pSortFactor": 25.0 if scalar else rng.choice(SPACE["pSortFactor"], n)}
+        res = ev.evaluate(ov)
+        ref = _per_column_fetch(ev, ov)
+    assert set(res.outputs) == set(ref)
+    for k, v in ref.items():
+        got = res.outputs[k]
+        assert got.dtype == v.dtype == (np.float64 if x64 else np.float32), k
+        assert got.shape == (n,), k
+        assert np.array_equal(_bits(got), _bits(v)), k
+    assert np.array_equal(_bits(res.total_cost),
+                          _bits(np.where(ref["valid"] > 0, ref["j_totalCost"], np.inf)))
+    assert ev.eval_cache_size() == 1
+
+
+def test_evaluate_keeps_each_output_dtype_exact():
+    def toy(cfg):       # float32, int32 and bool outputs from one row
+        x = cfg["pSortMB"]
+        return {"j_totalCost": (x * 1.5).astype(jnp.float32),
+                "valid": (x > 20.0).astype(jnp.int32),
+                "big": x > 100.0,
+                "count": x.astype(jnp.int32) + 16_777_217}   # not a float32
+
+    ev = ChunkedEvaluator(P, S, C, chunk=16, model_fn=toy)
+    vals = np.linspace(8.0, 400.0, 40)                 # three chunks
+    with observe() as ob:
+        res = ev.evaluate({"pSortMB": vals})
+    ref = _per_column_fetch(ev, {"pSortMB": vals})
+    want = {"j_totalCost": np.float32, "valid": np.int32, "big": np.bool_,
+            "count": np.int32}
+    assert {k: v.dtype for k, v in res.outputs.items()} == want
+    for k, v in ref.items():
+        assert np.array_equal(_bits(res.outputs[k]), _bits(v)), k
+    assert np.array_equal(res.outputs["count"],
+                          vals.astype(np.int32) + 16_777_217)
+    assert np.array_equal(res.outputs["big"], vals > 100.0)
+    # one copy per chunk per output dtype
+    assert ob.registry.counter("evaluator.d2h_copies").value == 3 * 3
 
 
 def test_padding_correct_at_non_divisible_sizes():
@@ -442,6 +510,22 @@ def test_sharded_matches_single_device_on_8_devices():
         assert np.array_equal(res.outputs["valid"], ref["valid"])
         ulps = ulp_distance(res.outputs["j_totalCost"], ref["j_totalCost"])
         assert ulps.max() <= CROSS_PROGRAM_MAX_ULP, ulps.max()
+        # the packed fetch of sharded columns: the same bits as copying
+        # each column of the model program's output on its own
+        batched, static, n = ev._split({"pSortMB": vals})
+        cols = {}
+        for start in range(0, n, ev.chunk):
+            stop = min(start + ev.chunk, n)
+            padded, _ = ev._pad(batched, start, stop)
+            for k, v in ev._eval_fn(padded, static).items():
+                cols.setdefault(k, []).append(np.asarray(v)[: stop - start])
+        assert set(cols) == set(res.outputs)
+        for k, v in cols.items():
+            v = np.concatenate(v)
+            got = res.outputs[k]
+            assert got.dtype == v.dtype, k
+            assert np.array_equal(got.view(f"u{got.itemsize}"),
+                                  v.view(f"u{v.itemsize}")), k
         top = search_topk(ev, {"pSortMB": list(vals)}, k=3)
         order = np.lexsort((np.arange(101), np.where(ref["valid"] > 0,
                             ref["j_totalCost"], np.inf)))[:3]

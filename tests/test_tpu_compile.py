@@ -4,7 +4,8 @@ The TPU compiler is installed alongside JAX, and it compiles for a chip that
 is described rather than attached.  These tests lower and compile, for one
 described v5e chip in float32, what ``chip_smoke.py`` runs on a real one:
 
-* the ``ChunkedEvaluator`` top-k body and full-output body at chunk 8192;
+* the ``ChunkedEvaluator`` top-k body and full-output body at chunk 8192,
+  and the program that packs the full output's 96 columns for one copy;
 * the planner's wave rollout (``vector_sim``) at 2048 scenarios x 64 jobs,
   and with fan-in DAG edges on racks at 512 scenarios x 658 jobs;
 * the ``seg_combine`` Pallas kernel at three shapes.
@@ -104,6 +105,21 @@ def test_whatif_evaluate_body_compiles_for_v5e(topo, one_chip):
     out = compiled.out_info
     assert out["j_totalCost"].shape == (ev.chunk,)
     assert out["j_totalCost"].dtype == jnp.float32
+
+
+def test_whatif_evaluate_pack_compiles_for_v5e(topo, one_chip):
+    from repro.search.evaluator import _pack_layout
+
+    ev = _whatif_evaluator(topo)
+    out = jax.eval_shape(ev._eval_fn, *_whatif_args(ev, one_chip))
+    layout = _pack_layout(out)
+    groups = [[jax.ShapeDtypeStruct(out[k].shape, out[k].dtype, sharding=one_chip)
+               for k in keys] for keys in layout]
+    compiled = ev._pack_fn.lower(groups).compile()
+    assert [len(keys) for keys in layout] == [96]
+    (packed,) = compiled.out_info
+    assert packed.shape == (96, ev.chunk) == (96, 8192)
+    assert packed.dtype == jnp.float32
 
 
 def test_wave_rollout_compiles_for_v5e(topo, one_chip):
