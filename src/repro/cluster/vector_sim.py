@@ -3,9 +3,9 @@
 The Python DES (:mod:`repro.cluster.sched`) costs ONE (workload, cluster)
 scenario per call — fine for a probe, hopeless for a capacity-planning grid.
 This module rolls out the same wave mechanics as a JAX program: one
-``lax.scan`` over *scheduling rounds* (global event times), ``vmap`` over
+``while_loop`` over *scheduling rounds* (global event times), ``vmap`` over
 scenarios, device-sharded over the scenario axis via the :mod:`repro.compat`
-shims — one compile per (step-count bucket, batch shape), exactly the
+shims — one compile per (step-cap bucket, batch shape), exactly the
 :class:`~repro.search.evaluator.ChunkedEvaluator` recipe.
 
 Model (wave-discrete, deterministic):
@@ -69,11 +69,11 @@ contended-incast agreement is gated at p95 (flat/uncontended rows stay
 rtol-exact, the standard contract).
 
 ``policy`` is 0 = fifo, 1 = fair, 2 = fair_preempt, 3 = capacity (the
-:data:`POLICIES` order).  :func:`simulate_batch` normalizes legacy inputs:
-a ``fair`` (B,) column is accepted as ``policy``, 1-D ``map_slots`` /
-``red_slots`` become one baseline class, ``speedup`` defaults to ones
-(classes are re-sorted fastest-first), ``queue`` / ``queue_frac`` default
-to a single queue.
+:data:`POLICIES` order; default fifo).  :func:`simulate_batch` fills in the
+rest: 1-D ``map_slots`` / ``red_slots`` become one baseline class,
+``speedup`` defaults to ones (classes are re-sorted fastest-first),
+``queue`` / ``queue_frac`` default to a single queue.  It refuses a column
+it does not know, so a misspelt knob cannot silently run the default.
 
 **Elastic fleets** (:mod:`repro.cloud`) add optional columns, all
 defaulting to the fixed-fleet zero:
@@ -99,7 +99,7 @@ contract: contention-free autoscaled cases stay rtol-exact).
 
 Use :func:`pack_trace` to turn a :class:`~repro.cluster.workload.
 WorkloadTrace` into per-job columns, and :func:`estimate_steps` to bound
-the scan length (truncated scenarios report ``converged == 0``, which the
+the loop's steps (truncated scenarios report ``converged == 0``, which the
 evaluator maps to ``valid == 0`` — the exact-simulator escape hatch, never
 a silent wrong number).
 """
@@ -129,6 +129,15 @@ _INF = jnp.inf
 #: scheduler-policy encoding of the ``policy`` scenario column — index into
 #: this tuple; matches ``ClusterConfig.scheduler`` names.
 POLICIES = ("fifo", "fair", "fair_preempt", "capacity")
+
+#: every column a scenario batch may carry (the module docstring's tables)
+_COLUMNS = frozenset({
+    "arrival", "n_maps", "n_reds", "map_cost", "red_work", "shuffle", "queue",
+    "map_slots", "red_slots", "speedup", "policy", "slowstart", "queue_frac",
+    "dep", "dep_kind", "topo_racks", "topo_cross_bw", "topo_oversub",
+    "autoscale", "high_water", "provision_latency", "extra_map_slots",
+    "extra_red_slots", "billing_quantum", "reclaim_rate",
+})
 
 
 def pack_trace(trace: WorkloadTrace) -> dict[str, np.ndarray]:
@@ -202,8 +211,7 @@ def estimate_steps(scen: Mapping[str, np.ndarray], *, margin: float = 2.0
     ms, rs = total("map_slots"), total("red_slots")
     waves = (np.ceil(n_maps / ms[:, None]).sum(axis=1)
              + np.ceil(n_reds / rs[:, None]).sum(axis=1))
-    pol = np.broadcast_to(np.asarray(scen.get("policy", scen.get("fair", 0.0))),
-                          waves.shape)
+    pol = np.broadcast_to(np.asarray(scen.get("policy", 0.0)), waves.shape)
     preempt = pol > 1.5
     n_jobs = scen["arrival"].shape[-1]
     est = int(np.max(waves, where=preempt, initial=0) * margin * 2.0)
@@ -727,14 +735,16 @@ def _compiled(devs: tuple, n_steps: int, with_fair: bool, with_preempt: bool,
 
 
 def _normalize(scen: Mapping[str, np.ndarray]) -> dict[str, np.ndarray]:
-    """Canonical scenario batch: legacy aliases resolved, class columns
-    2-D and sorted fastest-first, queue columns defaulted."""
+    """Canonical scenario batch: every column known, class columns 2-D and
+    sorted fastest-first, optional columns defaulted."""
+    unknown = sorted(set(scen) - _COLUMNS)
+    if unknown:
+        raise ValueError(f"unknown scenario columns: {unknown} "
+                         f"(known: {sorted(_COLUMNS)})")
     arrs = {k: np.asarray(v) for k, v in scen.items()}
     b = arrs["arrival"].shape[0]
     if "policy" not in arrs:
-        arrs["policy"] = arrs.pop("fair") if "fair" in arrs \
-            else np.zeros(b, dtype=np.float64)
-    arrs.pop("fair", None)
+        arrs["policy"] = np.zeros(b, dtype=np.float64)
     for k in ("map_slots", "red_slots"):
         if arrs[k].ndim == 1:
             arrs[k] = arrs[k][:, None]

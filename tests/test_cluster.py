@@ -216,6 +216,16 @@ def test_vector_sim_fair_converges_and_orders():
     assert np.isfinite(out["p95_latency"][0])
 
 
+def test_simulate_batch_refuses_an_unknown_column():
+    """A ``fair`` column, no longer an alias of ``policy``, is refused by
+    name instead of silently running the batch FIFO."""
+    tr = poisson_trace(CLASSES, 4, rate=1.0, seed=0)
+    scen = scenario_for(tr, ClusterConfig(num_nodes=2), 0.5)
+    scen["fair"] = scen.pop("policy") + 1.0
+    with pytest.raises(ValueError, match="'fair'"):
+        simulate_batch(scen)
+
+
 def test_truncation_is_flagged_not_silent():
     tr = poisson_trace(CLASSES, 8, rate=1.0, seed=0)
     out = simulate_batch(scenario_for(tr, ClusterConfig(num_nodes=2), 0.5),
@@ -295,7 +305,7 @@ def test_evaluator_invalid_rows(evaluator):
 
 
 def test_grid_search_and_topk_end_to_end(evaluator):
-    space = {"pNumNodes": [2.0, 4.0, 8.0], "schedFair": [0.0, 1.0]}
+    space = {"pNumNodes": [2.0, 4.0, 8.0], "schedPolicy": [0.0, 1.0]}
     plan = grid_search_ev(evaluator, space)
     assert np.isfinite(plan.best_cost) and plan.evaluations == 6
     assert set(plan.best_assignment) == set(space)
@@ -558,22 +568,18 @@ def test_evaluator_policy_axes_searchable(evaluator):
     assert np.isfinite(plan.best_cost) and plan.evaluations == 8
     top = search_topk(evaluator, space, k=3)
     assert top.best().cost == pytest.approx(plan.best_cost)
-    # schedPolicy overrides the legacy boolean; schedFair still works alone
-    legacy = evaluator.evaluate({"schedFair": np.array([1.0])})
-    modern = evaluator.evaluate({"schedPolicy": np.array([1.0])})
-    assert legacy.total_cost[0] == pytest.approx(modern.total_cost[0])
 
 
-def test_legacy_schedfair_still_controls_fair_base():
-    """A fair-scheduler base must not pin schedPolicy: sweeping the legacy
-    schedFair axis over {0, 1} still toggles FIFO vs fair."""
+def test_fair_base_seeds_sched_policy():
+    """A fair-scheduler base seeds schedPolicy = 1, as every base seeds its
+    own code, and the axis alone still toggles FIFO vs fair on it."""
     ev = ClusterEvaluator(CLASSES, n_jobs=8, n_seeds=1, chunk=8,
                           base=ClusterConfig(num_nodes=2, scheduler="fair"),
                           base_rate=0.2)
-    fifo = ev.exact_cost({"schedFair": 0.0})
-    fair = ev.exact_cost({"schedFair": 1.0})
+    fifo = ev.exact_cost({"schedPolicy": 0.0})
+    fair = ev.exact_cost({"schedPolicy": 1.0})
     assert fifo != fair
-    assert fair == pytest.approx(ev.exact_cost({}))   # base default is fair
+    assert fair == ev.exact_cost({})                  # base default is fair
 
 
 def test_inexpressible_base_fleet_rejected():

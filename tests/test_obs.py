@@ -504,6 +504,23 @@ def test_evaluator_spans_split_each_chunk_and_count_fetched_bytes():
     assert reg.counter("evaluator.rows").value == 190
 
 
+@pytest.mark.parametrize("planner", ["cluster", "cloud"])
+def test_planner_evaluate_records_the_split_and_each_chunks_scenarios(planner):
+    """Both capacity planners run one core: ``evaluate`` records its split
+    once and the scenario building of each chunk."""
+    import repro.api as api
+    from repro.cloud import CloudEvaluator
+    from repro.cluster import ClusterEvaluator, default_job_classes
+
+    cls = {"cluster": ClusterEvaluator, "cloud": CloudEvaluator}[planner]
+    ev = cls(default_job_classes(), n_jobs=4, n_seeds=1, chunk=4)
+    with api.observe() as ob:
+        ev.evaluate({"pMaxMapsPerNode": np.arange(1.0, 6.0)})   # 2 chunks
+    reg = ob.registry
+    assert reg.histogram("evaluator.prepare_s").count == 1
+    assert reg.histogram("cluster.build_scenarios_s").count == 2
+
+
 def _wave_lane(n_maps, arrivals, slots=2.0, cost=10.0):
     j = len(n_maps)
     return {"arrival": np.asarray(arrivals, float),
